@@ -198,17 +198,12 @@ def check_retrace(case: str, engine, state, data, chunk: int = 2) -> list[Findin
     the round closure defeats caching (unhashable static arg, fresh
     closure identity per call) and every driver chunk would re-trace.
     """
-    try:
-        from jax._src import test_util as jtu
-        counter = jtu.count_jit_tracing_cache_miss
-    except ImportError:  # internal API moved: degrade to a note, not a pass
-        return [Finding(case, "retrace",
-                        "jax internal tracing-cache counter unavailable on "
-                        "this jax version; retrace gate skipped", "note")]
+    from jax._src import test_util as jtu
+
     engine.lower_chunk(data, state=state, chunk=chunk, compile=False)  # warm
-    with counter() as misses:
+    with jtu.count_jit_tracing_cache_miss() as misses:
         engine.lower_chunk(data, state=state, chunk=chunk, compile=False)
-    n = misses[0] if misses else 0
+    n = misses()
     if n == 0:
         return []
     return [Finding(case, "retrace",

@@ -92,7 +92,10 @@ class HFLState(NamedTuple):
                          stale params under partial participation).
     z:      [G, K, ...]  client->group correction (zeros when unused).
     y:      [G, ...]     group->global correction (zeros when unused).
-    dyn:    [G, K, ...]  FedDyn gradient memory (zeros when unused).
+    dyn:    [G, K, ...]  FedDyn gradient memory -- only carried for
+                         ``algorithm="feddyn"``; None otherwise (no
+                         pytree leaves: a dead G*K*N buffer would cost
+                         as much device memory as the replicas).
     rng:    PRNG key for stochastic batching / participation sampling.
     round:  global round counter t.
     snap:   [G, ...]     global model each group last downloaded -- only
@@ -168,6 +171,7 @@ def hfl_init(params0: PyTree, cfg: HFLConfig, rng: jax.Array | None = None,
     G, K = cfg.num_groups, cfg.clients_per_group
     rng = jax.random.PRNGKey(0) if rng is None else rng
     dl = jnp.ones((G,), jnp.float32) if fault_download else None
+    feddyn = cfg.algorithm == "feddyn"
     if cfg.use_flat_state:
         packer = make_packer(params0)
         flat0 = packer.flatten(params0)
@@ -187,7 +191,7 @@ def hfl_init(params0: PyTree, cfg: HFLConfig, rng: jax.Array | None = None,
             params=params,
             z=packer.zeros((G, K)),
             y=packer.zeros((G,)),
-            dyn=packer.zeros((G, K)),
+            dyn=packer.zeros((G, K)) if feddyn else None,
             rng=rng,
             round=jnp.zeros((), jnp.int32),
             snap=snap,
@@ -211,7 +215,7 @@ def hfl_init(params0: PyTree, cfg: HFLConfig, rng: jax.Array | None = None,
         params=stacked,
         z=tu.tree_zeros_like(stacked),
         y=y0,
-        dyn=tu.tree_zeros_like(stacked),
+        dyn=tu.tree_zeros_like(stacked) if feddyn else None,
         rng=rng,
         round=jnp.zeros((), jnp.int32),
         snap=snap,

@@ -85,7 +85,7 @@ def _flat_kernel(lr, g_scale, x_ref, g_ref, z_ref, y_ref, m_ref, o_ref):
          + y_ref[...].astype(jnp.float32))
     x_new = x - lr * d
     if m_ref is not None:
-        x_new = jnp.where(m_ref[0, 0] != 0, x_new, x)
+        x_new = jnp.where(m_ref[0, 0, 0] != 0, x_new, x)
     o_ref[...] = x_new.astype(o_ref.dtype)
 
 
@@ -124,8 +124,11 @@ def mtgc_update_flat(x, g, z, y, mask=None, *, lr: float, g_scale: float = 1.0,
                 pl.BlockSpec((1, br, LANE), lambda i, j: (i // K, j, 0))]
     operands = [xs, gs, zs, ys]
     if mask is not None:
-        in_specs.append(pl.BlockSpec((1, 1), lambda i, j: (i, 0)))
-        operands.append(mask.reshape(G * K, 1).astype(jnp.float32))
+        # The per-row scalar is an (R, 1, 1) operand with a (1, 1, 1)
+        # block: Mosaic needs a block's last two dims to be multiples of
+        # (8, 128) or equal to the array's.
+        in_specs.append(pl.BlockSpec((1, 1, 1), lambda i, j: (i, 0, 0)))
+        operands.append(mask.reshape(G * K, 1, 1).astype(jnp.float32))
         kern = functools.partial(_flat_kernel, float(lr), float(g_scale))
     else:
         kern = functools.partial(

@@ -3,8 +3,9 @@
 Both kernels consume a batch of per-row upload vectors -- ``u: [R, N]``
 where a row is one client's (or one group's) whole-model delta in the
 contiguous flat layout (core/packer.py) -- plus one per-row scalar
-(quantization scale or top-k threshold) fed through the same ``(1, 1)``
-block-spec idiom the fused MTGC kernel uses for participation masks, so
+(quantization scale or top-k threshold) fed as an ``(R, 1, 1)`` operand
+with a ``(1, 1, 1)`` block -- the idiom the fused MTGC kernel uses for
+participation masks, and the one the TPU compiler accepts -- so
 the scalar is read once per grid row and the quantize -> dequantize
 round trip happens entirely in-register: the int8 payload is never
 materialized in HBM (bytes on the wire are accounted analytically in
@@ -55,7 +56,7 @@ def _prep(a, R: int, n: int, rows_p: int, pad: int):
 
 
 def _int8_kernel(u_ref, n_ref, s_ref, o_ref):
-    scale = s_ref[0, 0]
+    scale = s_ref[0, 0, 0]
     u = u_ref[...].astype(jnp.float32)
     q = jnp.floor(u / scale + n_ref[...].astype(jnp.float32))
     q = jnp.clip(q, -127.0, 127.0)
@@ -77,16 +78,16 @@ def int8_roundtrip(u, scale, noise, *, block_rows: int = DEFAULT_BLOCK_ROWS,
         _int8_kernel,
         grid=grid,
         in_specs=[row_spec, row_spec,
-                  pl.BlockSpec((1, 1), lambda i, j: (i, 0))],
+                  pl.BlockSpec((1, 1, 1), lambda i, j: (i, 0, 0))],
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((R, rows_p, LANE), dtype),
         interpret=interpret,
-    )(us, ns, scale.reshape(R, 1).astype(jnp.float32))
+    )(us, ns, scale.reshape(R, 1, 1).astype(jnp.float32))
     return out.reshape(R, rows_p * LANE)[:, :n]
 
 
 def _topk_kernel(u_ref, t_ref, o_ref):
-    thresh = t_ref[0, 0]
+    thresh = t_ref[0, 0, 0]
     u = u_ref[...]
     o_ref[...] = jnp.where(jnp.abs(u) >= thresh, u,
                            jnp.zeros_like(u)).astype(o_ref.dtype)
@@ -105,9 +106,9 @@ def topk_mask(u, thresh, *, block_rows: int = DEFAULT_BLOCK_ROWS,
     out = pl.pallas_call(
         _topk_kernel,
         grid=grid,
-        in_specs=[row_spec, pl.BlockSpec((1, 1), lambda i, j: (i, 0))],
+        in_specs=[row_spec, pl.BlockSpec((1, 1, 1), lambda i, j: (i, 0, 0))],
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((R, rows_p, LANE), dtype),
         interpret=interpret,
-    )(us, thresh.reshape(R, 1).astype(dtype))
+    )(us, thresh.reshape(R, 1, 1).astype(dtype))
     return out.reshape(R, rows_p * LANE)[:, :n]

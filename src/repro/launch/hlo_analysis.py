@@ -187,12 +187,8 @@ class HloCosts:
 
 
 def xla_cost_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized across jax versions (a plain
-    dict in newer releases, a one-dict-per-device list in older ones)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """``compiled.cost_analysis()`` as a dict (empty when XLA gives none)."""
+    return compiled.cost_analysis() or {}
 
 
 def _shape_of(text: str):

@@ -944,15 +944,43 @@ def _build_sharded_round(
 # --------------------------------------------------------------------- CLI
 
 
+def setup_run(arch: str, spec, *, smoke: bool, seq: int, batch: int,
+              seed: int, shards: int):
+    """Model, engine and packed token data for one ``fit`` of ``arch``.
+
+    ``smoke`` trains ``cfg.reduced()`` (2 layers, d<=512) in place of the
+    published widths. Returns ``(cfg, params, engine, data)``.
+    """
+    import numpy as np
+
+    from repro.configs import get_arch
+    from repro.core.api import build
+    from repro.data.lm import make_lm_tokens
+    from repro.models.transformer import build_model
+
+    cfg = get_arch(arch)
+    if smoke:
+        cfg = cfg.reduced()
+    bundle = build_model(cfg)
+    rng = np.random.default_rng(seed)
+    toks, _ = make_lm_tokens(rng, cfg.vocab_size, 200_000)
+    params = bundle.init(jax.random.PRNGKey(seed))
+    engine = build(spec, bundle.loss)
+    data = engine.pack_tokens(
+        toks, batch_size=batch, seq_len=seq, shards=shards,
+        rng=rng, key=jax.random.PRNGKey(seed + 1))
+    return cfg, params, engine, data
+
+
 def main() -> None:
     from repro.core.api import (
         ExperimentSpec,
         RoundSchedule,
         add_spec_args,
-        build,
         fit,
         spec_from_args,
     )
+    from repro.launch.compile_cache import enable_compile_cache
 
     # Spec flags (--levels/--E/--H/--algorithm/--lr/--state-layout/...) are
     # generated from repro.api's one declarative CLI table; this entry
@@ -965,7 +993,7 @@ def main() -> None:
     add_spec_args(ap, defaults=defaults, exclude=("backend",))
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
-                    help="reduced config on the host CPU (2 layers, d<=512)")
+                    help="reduced config (2 layers, d<=512)")
     ap.add_argument("--rounds", type=int, default=2)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--batch", type=int, default=2)
@@ -978,31 +1006,16 @@ def main() -> None:
                     help="packed batch blocks per client uploaded once "
                          "(on-device batch selection)")
     args = ap.parse_args()
-
-    import numpy as np
-
-    from repro.configs import get_arch
-    from repro.data.lm import make_lm_tokens
-    from repro.models.transformer import build_model
-
-    cfg = get_arch(args.arch)
-    if args.smoke:
-        cfg = cfg.reduced()
-    bundle = build_model(cfg)
-    rng = np.random.default_rng(args.seed)
-    toks, _ = make_lm_tokens(rng, cfg.vocab_size, 200_000)
-    params = bundle.init(jax.random.PRNGKey(args.seed))
-    n_params = sum(x.size for x in jax.tree.leaves(params))
+    enable_compile_cache()
 
     spec = spec_from_args(args, defaults=defaults, backend="sharded",
                           microbatches=1)
+    cfg, params, engine, data = setup_run(
+        args.arch, spec, smoke=args.smoke, seq=args.seq, batch=args.batch,
+        seed=args.seed, shards=args.shards)
+    n_params = sum(x.size for x in jax.tree.leaves(params))
     print(f"[train] arch={cfg.name} params={n_params/1e6:.1f}M "
           f"algo={spec.algorithm}")
-
-    engine = build(spec, bundle.loss)
-    data = engine.pack_tokens(
-        toks, batch_size=args.batch, seq_len=args.seq, shards=args.shards,
-        rng=rng, key=jax.random.PRNGKey(args.seed + 1))
     if spec.population is not None:
         G, K = spec.levels
         if spec.client_state == "stateful":

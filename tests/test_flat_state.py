@@ -102,9 +102,12 @@ def _run_engine(cfg, batches, rounds=3):
 
 def _assert_state_parity(st_tree, st_flat, m_tree, m_flat, tag):
     for name in ("params", "z", "y", "dyn"):
+        a, b = getattr(st_tree, name), getattr(st_flat, name)
+        if a is None or b is None:  # dyn: carried for FedDyn only
+            assert a is None and b is None, f"{tag}.{name}"
+            continue
         np.testing.assert_allclose(
-            np.asarray(getattr(st_tree, name)["w"]),
-            np.asarray(as_tree(getattr(st_flat, name))["w"]),
+            np.asarray(a["w"]), np.asarray(as_tree(b)["w"]),
             rtol=1e-5, atol=1e-6, err_msg=f"{tag}.{name}")
     for name in m_tree._fields:
         np.testing.assert_allclose(
