@@ -60,6 +60,7 @@ front).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, NamedTuple, Protocol, runtime_checkable
 
 import jax
@@ -1002,6 +1003,7 @@ def build(spec: ExperimentSpec, loss_fn: LossFn) -> Engine:
     return _ENGINES[spec.backend](spec, loss_fn)
 
 
+@functools.partial(jax.profiler.annotate_function, name="repro.fit")
 def fit(
     engine: Engine,
     data: PackedBatches,
@@ -1063,6 +1065,9 @@ def fit(
     checkpoint in ``checkpoint_path`` (if any) and runs only the
     remaining ``T - step`` rounds -- bit-exact with the uninterrupted run
     (tests/test_checkpoint.py).
+
+    A profiler trace shows each call as the host span ``repro.fit``, with
+    the driver's ``repro.dispatch`` and ``repro.fetch`` inside it.
     """
     if state is None:
         _require(params is not None,

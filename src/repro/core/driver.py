@@ -528,9 +528,15 @@ def dispatch_chunk(
     scatter) while the device scans the chunk; only touching the results
     with ``np.asarray`` blocks. With ``donate`` the input state's buffers
     are consumed.
+
+    The host span ``repro.dispatch`` (argument ``rounds``, the chunk's
+    round count) covers fetching the runner through the enqueue, so a
+    trace shows a recompile as a long span.
     """
-    runner = _chunk_runner(round_fn, eval_fn, donate)
-    out = runner(state, data, jnp.asarray(eval_mask))
+    with jax.profiler.TraceAnnotation("repro.dispatch",
+                                      rounds=len(eval_mask)):
+        runner = _chunk_runner(round_fn, eval_fn, donate)
+        out = runner(state, data, jnp.asarray(eval_mask))
     state, rng = out[0], out[1]
     evals = out[3] if eval_fn is not None else None
     return state, data.replace_rng(rng), out[2], evals
@@ -587,7 +593,8 @@ def run_rounds(
     hooks checkpoint autosave here.
 
     Returns ``(state, data, Horizon)`` -- ``data`` carries the advanced
-    selection rng so horizons can be continued.
+    selection rng so horizons can be continued. The host's wait for the
+    chunks' metrics is the host span ``repro.fetch``.
     """
     assert T >= 1 and eval_every >= 1
     if chunk is not None and chunk < 0:
@@ -621,12 +628,13 @@ def run_rounds(
     def _cat(*xs):
         return np.concatenate([np.asarray(x) for x in xs])
 
-    metrics = jax.tree.map(_cat, *mets)
     mask_all = np.concatenate(masks)
     eval_rounds = np.nonzero(mask_all)[0] + 1
     evals = None
-    if eval_fn is not None:
-        evals = jax.tree.map(lambda *xs: _cat(*xs)[mask_all], *evs)
+    with jax.profiler.TraceAnnotation("repro.fetch"):
+        metrics = jax.tree.map(_cat, *mets)
+        if eval_fn is not None:
+            evals = jax.tree.map(lambda *xs: _cat(*xs)[mask_all], *evs)
     report = GuardReport(rollbacks, retries) if guard is not None else None
     return state, data, Horizon(metrics, evals, eval_rounds, data, None, report)
 

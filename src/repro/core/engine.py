@@ -229,7 +229,8 @@ def hfl_init(params0: PyTree, cfg: HFLConfig, rng: jax.Array | None = None,
 def _client_grads(loss_fn: Callable, params: PyTree, batch: PyTree):
     """(loss, grad) of the local loss, vmapped over the [G, K] leading axes."""
     vg = jax.value_and_grad(loss_fn)
-    return jax.vmap(jax.vmap(vg))(params, batch)
+    with jax.named_scope("client_step"):
+        return jax.vmap(jax.vmap(vg))(params, batch)
 
 
 def make_global_round(
@@ -473,35 +474,36 @@ def _build_global_round(
             def step(carry, batch):
                 x = carry
                 loss, g = _client_grads(loss_fn, x, batch)
-                if use_fused:
-                    # Hot-spot AXPY fused through VMEM (Alg. 1 line 7).
-                    x_new = jax.tree.map(
-                        lambda xi, gi, zi, yi: kops.mtgc_update(
-                            xi, gi, zi, yi, lr=lr, mode=fused_mode),
-                        x, g, z, y_b,
-                    )
-                else:
-                    # Corrected direction: g + z + y (MTGC); baselines
-                    # toggle terms.
-                    d = g
-                    if use_z:
-                        d = tu.tree_add(d, z)
-                    if use_y:
-                        d = tu.tree_add(d, y_b)
-                    if use_prox:
-                        d = jax.tree.map(
-                            lambda di, xi, ai: di + cfg.prox_mu * (xi - ai),
-                            d, x, anchor)
-                    if use_dyn:
-                        d = jax.tree.map(
-                            lambda di, mi, xi, ai: di - mi + cfg.feddyn_alpha * (xi - ai),
-                            d, dyn, x, anchor,
+                with jax.named_scope("local_update"):
+                    if use_fused:
+                        # Hot-spot AXPY fused through VMEM (Alg. 1 line 7).
+                        x_new = jax.tree.map(
+                            lambda xi, gi, zi, yi: kops.mtgc_update(
+                                xi, gi, zi, yi, lr=lr, mode=fused_mode),
+                            x, g, z, y_b,
                         )
-                    x_new = jax.tree.map(lambda xi, di: xi - lr * di, x, d)
-                if am is not None:
-                    x = tu.tree_select(am, x_new, x)
-                else:
-                    x = x_new
+                    else:
+                        # Corrected direction: g + z + y (MTGC); baselines
+                        # toggle terms.
+                        d = g
+                        if use_z:
+                            d = tu.tree_add(d, z)
+                        if use_y:
+                            d = tu.tree_add(d, y_b)
+                        if use_prox:
+                            d = jax.tree.map(
+                                lambda di, xi, ai: di + cfg.prox_mu * (xi - ai),
+                                d, x, anchor)
+                        if use_dyn:
+                            d = jax.tree.map(
+                                lambda di, mi, xi, ai: di - mi + cfg.feddyn_alpha * (xi - ai),
+                                d, dyn, x, anchor,
+                            )
+                        x_new = jax.tree.map(lambda xi, di: xi - lr * di, x, d)
+                    if am is not None:
+                        x = tu.tree_select(am, x_new, x)
+                    else:
+                        x = x_new
                 return x, step_loss_mean(loss, am, n_act)
 
             x, losses = jax.lax.scan(step, x, batches_eh)
@@ -524,13 +526,14 @@ def _build_global_round(
                 def step(xf, batch):
                     loss, g = _client_grads(loss_fn, packer.unflatten(xf), batch)
                     gf = packer.flatten(g)
-                    xf = FlatBuffers(
-                        {k: kops.mtgc_update_flat(
-                            xf.bufs[k], gf.bufs[k], z.bufs[k], y.bufs[k],
-                            am, lr=lr, mode=fused_mode)
-                         for k in xf.bufs},
-                        packer,
-                    )
+                    with jax.named_scope("local_update"):
+                        xf = FlatBuffers(
+                            {k: kops.mtgc_update_flat(
+                                xf.bufs[k], gf.bufs[k], z.bufs[k], y.bufs[k],
+                                am, lr=lr, mode=fused_mode)
+                             for k in xf.bufs},
+                            packer,
+                        )
                     return xf, step_loss_mean(loss, am, n_act)
 
                 return jax.lax.scan(step, x, batches_eh)
@@ -566,7 +569,8 @@ def _build_global_round(
                 extra = [t for t, used in ((z_t, use_z), (y_t, use_y),
                                            (anchor_t, use_prox or use_dyn),
                                            (dyn_t, use_dyn)) if used]
-                x_t = jax.tree.map(upd, x_t, g, *extra)
+                with jax.named_scope("local_update"):
+                    x_t = jax.tree.map(upd, x_t, g, *extra)
                 return x_t, step_loss_mean(loss, am, n_act)
 
             x_t, losses = jax.lax.scan(step, packer.unflatten(x), batches_eh)
@@ -598,97 +602,98 @@ def _build_global_round(
             x_end, losses = local_phase(x, z, y, dyn, anchor, batches_eh,
                                         am, n_act)
 
-            # Upload view: compression first -- the wire carries the
-            # dequantized delta, so corruption faults then rewrite (and
-            # the defense screens) exactly what the group server would
-            # reconstruct; clean/frozen clients keep their exact bits
-            # either way (where-selects, never arithmetic).
-            x_up = x_end
-            if comp_c:
-                delta = tu.tree_sub(x_end, x)
-                u = tu.tree_add(delta, efc) if ef_c else delta
-                deq = _cmp.roundtrip(
-                    u, mode=comp.client_mode, lead_ndim=2,
-                    frac=comp.topk_frac, key=ek, dispatch=comp_dispatch)
-                x_cmp = tu.tree_add(x, deq)
-                x_up = (tu.tree_select(am, x_cmp, x_end)
-                        if am is not None else x_cmp)
-            if f_corrupt:
-                x_up = _flt.corrupt_uploads(x, x_up, fm.corrupt * am, faults)
-            if defended:
-                x_up, ok = _flt.screen_and_clip(x, x_up, defense)
-                smask = am * ok
-                scr = jnp.sum(am) - jnp.sum(smask)
-                n_srv = jnp.maximum(jnp.sum(smask), 1.0)
-            else:
-                smask = am
-                n_srv = n_act
-            # Correction-state view: z is client-side state -- the client
-            # updates it from its *own* local model plus the broadcast it
-            # receives -- so the error-feedback residual re-applied on the
-            # wire must never enter z (feeding released residual mass back
-            # through the correction destabilizes EF). Uncompressed, the
-            # wire view is the local model and the legacy program is
-            # untouched, screening and clipping included.
-            x_loc = x_up
-            if comp_c:
-                x_loc = x_end
+            with jax.named_scope("group_agg"):
+                # Upload view: compression first -- the wire carries the
+                # dequantized delta, so corruption faults then rewrite (and
+                # the defense screens) exactly what the group server would
+                # reconstruct; clean/frozen clients keep their exact bits
+                # either way (where-selects, never arithmetic).
+                x_up = x_end
+                if comp_c:
+                    delta = tu.tree_sub(x_end, x)
+                    u = tu.tree_add(delta, efc) if ef_c else delta
+                    deq = _cmp.roundtrip(
+                        u, mode=comp.client_mode, lead_ndim=2,
+                        frac=comp.topk_frac, key=ek, dispatch=comp_dispatch)
+                    x_cmp = tu.tree_add(x, deq)
+                    x_up = (tu.tree_select(am, x_cmp, x_end)
+                            if am is not None else x_cmp)
                 if f_corrupt:
-                    x_loc = _flt.corrupt_uploads(x, x_loc, fm.corrupt * am,
-                                                 faults)
-            if ef_c:
-                # Residual carries forward only for contributions that
-                # entered the aggregate: a screened or inactive client
-                # leaves its error-feedback state untouched.
-                err = tu.tree_sub(u, deq)
-                efc = (tu.tree_select(smask, err, efc)
-                       if smask is not None else err)
+                    x_up = _flt.corrupt_uploads(x, x_up, fm.corrupt * am, faults)
+                if defended:
+                    x_up, ok = _flt.screen_and_clip(x, x_up, defense)
+                    smask = am * ok
+                    scr = jnp.sum(am) - jnp.sum(smask)
+                    n_srv = jnp.maximum(jnp.sum(smask), 1.0)
+                else:
+                    smask = am
+                    n_srv = n_act
+                # Correction-state view: z is client-side state -- the client
+                # updates it from its *own* local model plus the broadcast it
+                # receives -- so the error-feedback residual re-applied on the
+                # wire must never enter z (feeding released residual mass back
+                # through the correction destabilizes EF). Uncompressed, the
+                # wire view is the local model and the legacy program is
+                # untouched, screening and clipping included.
+                x_loc = x_up
+                if comp_c:
+                    x_loc = x_end
+                    if f_corrupt:
+                        x_loc = _flt.corrupt_uploads(x, x_loc, fm.corrupt * am,
+                                                     faults)
+                if ef_c:
+                    # Residual carries forward only for contributions that
+                    # entered the aggregate: a screened or inactive client
+                    # leaves its error-feedback state untouched.
+                    err = tu.tree_sub(u, deq)
+                    efc = (tu.tree_select(smask, err, efc)
+                           if smask is not None else err)
 
-            # Group aggregation (line 8): xbar_j = mean over (active,
-            # surviving) clients (realized-count or expected-count
-            # denominator per weighting).
-            if smask is not None:
-                xbar = tu.tree_masked_mean(x_up, smask, axis=1,
-                                           denom=cdenom)            # [G, ...]
-            else:
-                xbar = tu.tree_mean(x_up, axis=1)                   # [G, ...]
-            xbar_b = tu.tree_broadcast_to_axis(xbar, 1, K)          # [G, K, ...]
+                # Group aggregation (line 8): xbar_j = mean over (active,
+                # surviving) clients (realized-count or expected-count
+                # denominator per weighting).
+                if smask is not None:
+                    xbar = tu.tree_masked_mean(x_up, smask, axis=1,
+                                               denom=cdenom)            # [G, ...]
+                else:
+                    xbar = tu.tree_mean(x_up, axis=1)                   # [G, ...]
+                xbar_b = tu.tree_broadcast_to_axis(xbar, 1, K)          # [G, K, ...]
 
-            diff = tu.tree_sub(x_up, xbar_b)
-            if smask is not None:
-                drift = tu.tree_masked_sq_norm(diff, smask) / n_srv
-            else:
-                drift = tu.tree_sq_norm(diff) / (G * K)
+                diff = tu.tree_sub(x_up, xbar_b)
+                if smask is not None:
+                    drift = tu.tree_masked_sq_norm(diff, smask) / n_srv
+                else:
+                    drift = tu.tree_sq_norm(diff) / (G * K)
 
-            # Client-group correction update (line 9):
-            #   z_i += (x_{i,H} - xbar_j) / (H * lr)
-            # Gated on the screen mask: a screened contribution never
-            # integrates into the correction state.
-            if use_z:
-                z_new = jax.tree.map(
-                    lambda zi, xe, xb: zi + (xe - xb) / (H * lr), z, x_loc, xbar_b
-                )
-                z = tu.tree_select(smask, z_new, z) if smask is not None else z_new
-            # Model dissemination: every active client restarts from the
-            # group model; inactive clients stay frozen. Under the defense,
-            # active-but-screened clients also download -- that is what
-            # heals a corrupted client -- unless the group has no surviving
-            # contribution at all (its hardened mean is an exact, unusable
-            # zero), in which case the group's active clients revert to
-            # their group-round start model: a screened upload must never
-            # survive in a replica, or the global recovery mean would
-            # integrate it anyway (`x` still holds the round-start
-            # replicas here; for frozen clients it is bit-identical to
-            # x_up, so only the fully-screened case changes).
-            if smask is None:
-                x = xbar_b
-            elif defended:
-                has_srv = (jnp.sum(smask, axis=1) > 0).astype(jnp.float32)
-                x = tu.tree_select(am * has_srv[:, None], xbar_b, x)
-            else:
-                x = tu.tree_select(am, xbar_b, x_up)
-            out = (losses, drift, scr) if defended else (losses, drift)
-            return (x, z, y, dyn, anchor, efc), out
+                # Client-group correction update (line 9):
+                #   z_i += (x_{i,H} - xbar_j) / (H * lr)
+                # Gated on the screen mask: a screened contribution never
+                # integrates into the correction state.
+                if use_z:
+                    z_new = jax.tree.map(
+                        lambda zi, xe, xb: zi + (xe - xb) / (H * lr), z, x_loc, xbar_b
+                    )
+                    z = tu.tree_select(smask, z_new, z) if smask is not None else z_new
+                # Model dissemination: every active client restarts from the
+                # group model; inactive clients stay frozen. Under the defense,
+                # active-but-screened clients also download -- that is what
+                # heals a corrupted client -- unless the group has no surviving
+                # contribution at all (its hardened mean is an exact, unusable
+                # zero), in which case the group's active clients revert to
+                # their group-round start model: a screened upload must never
+                # survive in a replica, or the global recovery mean would
+                # integrate it anyway (`x` still holds the round-start
+                # replicas here; for frozen clients it is bit-identical to
+                # x_up, so only the fully-screened case changes).
+                if smask is None:
+                    x = xbar_b
+                elif defended:
+                    has_srv = (jnp.sum(smask, axis=1) > 0).astype(jnp.float32)
+                    x = tu.tree_select(am * has_srv[:, None], xbar_b, x)
+                else:
+                    x = tu.tree_select(am, xbar_b, x_up)
+                out = (losses, drift, scr) if defended else (losses, drift)
+                return (x, z, y, dyn, anchor, efc), out
 
         # --- Round initialization (lines 2-4) ---------------------------
         # Group model init is implicit: params enter equal across clients.
@@ -800,263 +805,264 @@ def _build_global_round(
             screened = jnp.zeros((), jnp.float32)
 
         # --- Global aggregation (line 10) --------------------------------
-        efg = state.efg if ef_g else None
-        if ef_g and efg is None:
-            raise ValueError(
-                "group-link error feedback carries per-group residuals in "
-                "the state: build it with hfl_init(..., ef_group=True) "
-                "(repro.api.build does this for you)")
+        with jax.named_scope("global_agg"):
+            efg = state.efg if ef_g else None
+            if ef_g and efg is None:
+                raise ValueError(
+                    "group-link error feedback carries per-group residuals in "
+                    "the state: build it with hfl_init(..., ef_group=True) "
+                    "(repro.api.build does this for you)")
 
-        def compress_group(xbar_j, gref, gact):
-            """Compress each group's report delta against its round-start
-            model -- the reference both ends of the link share -- and
-            where-select so non-reporting groups' recovered means keep
-            their exact bits. Returns (xbar_j', u, deq) for the EF carry.
-            """
-            gdelta = tu.tree_sub(xbar_j, gref)
-            ug = tu.tree_add(gdelta, efg) if ef_g else gdelta
-            deqg = _cmp.roundtrip(
-                ug, mode=comp.group_mode, lead_ndim=1,
-                frac=comp.topk_frac, key=kg if comp_stoch else None,
-                dispatch=comp_dispatch)
-            xbar_c = tu.tree_add(gref, deqg)
-            if gact is not None:
-                xbar_c = tu.tree_select(gact, xbar_c, xbar_j)
-            return xbar_c, ug, deqg
+            def compress_group(xbar_j, gref, gact):
+                """Compress each group's report delta against its round-start
+                model -- the reference both ends of the link share -- and
+                where-select so non-reporting groups' recovered means keep
+                their exact bits. Returns (xbar_j', u, deq) for the EF carry.
+                """
+                gdelta = tu.tree_sub(xbar_j, gref)
+                ug = tu.tree_add(gdelta, efg) if ef_g else gdelta
+                deqg = _cmp.roundtrip(
+                    ug, mode=comp.group_mode, lead_ndim=1,
+                    frac=comp.topk_frac, key=kg if comp_stoch else None,
+                    dispatch=comp_dispatch)
+                xbar_c = tu.tree_add(gref, deqg)
+                if gact is not None:
+                    xbar_c = tu.tree_select(gact, xbar_c, xbar_j)
+                return xbar_c, ug, deqg
 
-        if async_mode:
-            # Staleness-aware merge of the groups reporting this window:
-            # reports enter a weighted mean -- report cadence (rep) x policy
-            # weight (dw) x the participation estimator -- and non-reporting
-            # groups neither upload nor download (see core/staleness.py).
-            if masked:
-                gact = (jnp.sum(cmask, axis=1) > 0).astype(jnp.float32)
-                gup = jnp.sum(rep * gact)  # reports actually sent (pre-screen)
-                # Recovery, not estimation: active replicas of group j all
-                # hold the disseminated xbar_j from its last live iteration.
+            if async_mode:
+                # Staleness-aware merge of the groups reporting this window:
+                # reports enter a weighted mean -- report cadence (rep) x policy
+                # weight (dw) x the participation estimator -- and non-reporting
+                # groups neither upload nor download (see core/staleness.py).
+                if masked:
+                    gact = (jnp.sum(cmask, axis=1) > 0).astype(jnp.float32)
+                    gup = jnp.sum(rep * gact)  # reports actually sent (pre-screen)
+                    # Recovery, not estimation: active replicas of group j all
+                    # hold the disseminated xbar_j from its last live iteration.
+                    xbar_j = tu.tree_masked_mean(x, cmask, axis=1)
+                    if defended and defense.screen_nonfinite:
+                        # Backstop group-level screen: a recovered report that
+                        # still carries non-finite bits never enters the merge
+                        # (counts every active client it would have spoken for).
+                        gfin = _flt.all_finite_mask(xbar_j, 1)
+                        screened = screened + jnp.sum(
+                            cmask * ((gact * (1.0 - gfin))[:, None]))
+                        gact = gact * gfin
+                    obs = rep * gact
+                else:
+                    xbar_j = jax.tree.map(lambda xi: xi[:, 0], x)
+                    obs = rep
+                    gup = jnp.sum(rep)
+                if plan.needs_snapshots:
+                    if state.snap is None or state.glob is None:
+                        raise ValueError(
+                            "staleness='delay_compensated' carries per-group "
+                            "download snapshots in the state: build it with "
+                            "hfl_init(..., staleness_snapshots=True) "
+                            "(repro.api.build does this for you)")
+                    # First-order delay compensation: shift a stale report by
+                    # the global progress its group missed since it last
+                    # downloaded (glob - snap_g; exactly zero for fresh groups).
+                    xbar_used = jax.tree.map(
+                        lambda xj, gl, sn: xj + (jnp.expand_dims(gl, 0) - sn),
+                        xbar_j, state.glob, state.snap)
+                else:
+                    xbar_used = xbar_j
+
+                w = rep * dw                        # [G] deterministic weights
+                if partial and ht:
+                    # Horvitz-Thompson over reachable groups composed with the
+                    # deterministic report/policy weights: an empty reachable
+                    # report contributes an exact zero while the denominator
+                    # stays the expected reporting mass.
+                    wsum = w * gmask
+                    sup = wsum * gact
+                    den = (gdenom / G) * jnp.sum(w)
+                elif masked:
+                    wsum = w * gact
+                    sup = wsum
+                    den_raw = jnp.sum(wsum)
+                    den = jnp.where(den_raw > 0, den_raw, 1.0)
+                else:
+                    # >= 1 always: the pace-setting group (r_g = 1) reports
+                    # every window at full weight.
+                    wsum = w
+                    sup = wsum
+                    den = jnp.sum(w)
+
+                def _stale_merge(v):
+                    live = tu.expand_mask(sup, v) != 0
+                    return jnp.sum(
+                        jnp.where(live, v, 0) * tu.expand_mask(wsum, v),
+                        axis=0) / den
+
+                xbar = jax.tree.map(_stale_merge, xbar_used)
+                gdrift = tu.tree_masked_sq_norm(
+                    tu.tree_sub(xbar_j, tu.tree_broadcast_to_axis(xbar, 0, G)), obs
+                ) / jnp.maximum(jnp.sum(obs), 1.0)
+            elif masked and (fault_mode or defended or comp_g):
+                # The legacy recovery/estimation split of tree_group_global_mean,
+                # opened up so group-timeout faults, the group-level finite
+                # screen and group-link compression can compose into the
+                # estimation mask between the two stages (recovery over active
+                # replicas is unchanged).
                 xbar_j = tu.tree_masked_mean(x, cmask, axis=1)
+                gact = (jnp.sum(cmask, axis=1) > 0).astype(jnp.float32)
+                if f_timeout:
+                    # A timed-out group misses the global exchange entirely:
+                    # no upload, no y update, no download -- frozen this round.
+                    gact = gact * tm_keep
+                gup = jnp.sum(gact)  # reports actually sent (pre-screen)
+                if comp_g:
+                    # Compression happens at the upload, i.e. after the
+                    # timeout composition (a timed-out group never sent bytes,
+                    # so its residual must not advance) and before the finite
+                    # screen (the backstop screens the dequantized report).
+                    gref = tu.tree_masked_mean(state.params, cmask, axis=1)
+                    xbar_srv = xbar_j  # group server's own (pre-wire) aggregate
+                    xbar_j, ug, deqg = compress_group(xbar_j, gref, gact)
                 if defended and defense.screen_nonfinite:
-                    # Backstop group-level screen: a recovered report that
-                    # still carries non-finite bits never enters the merge
-                    # (counts every active client it would have spoken for).
                     gfin = _flt.all_finite_mask(xbar_j, 1)
                     screened = screened + jnp.sum(
                         cmask * ((gact * (1.0 - gfin))[:, None]))
                     gact = gact * gfin
-                obs = rep * gact
+                if ht:
+                    xbar_j0 = jax.tree.map(
+                        lambda v: jnp.where(tu.expand_mask(gact, v) != 0, v, 0),
+                        xbar_j)
+                    xbar = tu.tree_masked_mean(xbar_j0, gmask, axis=0,
+                                               denom=gdenom)
+                else:
+                    xbar = tu.tree_masked_mean(xbar_j, gact, axis=0)
+                gdrift = tu.tree_masked_sq_norm(
+                    tu.tree_sub(xbar_j, tu.tree_broadcast_to_axis(xbar, 0, G)), gact
+                ) / jnp.maximum(jnp.sum(gact), 1.0)
+            elif partial:
+                # A group with zero sampled clients never feeds the y update or
+                # dissemination of its own replicas (gact gating). Under
+                # realized-count weighting it is also renormalized out of the
+                # global mean; under inverse_prob every *reachable* group enters
+                # the Horvitz-Thompson sum, an empty one contributing zero --
+                # see tree_group_global_mean for the recovery/estimation split.
+                xbar_j, xbar, gact = tu.tree_group_global_mean(
+                    x, cmask, gmask if ht else None, gdenom)
+                gup = jnp.sum(gact)
+                gdrift = tu.tree_masked_sq_norm(
+                    tu.tree_sub(xbar_j, tu.tree_broadcast_to_axis(xbar, 0, G)), gact
+                ) / jnp.maximum(jnp.sum(gact), 1.0)
             else:
-                xbar_j = jax.tree.map(lambda xi: xi[:, 0], x)
-                obs = rep
-                gup = jnp.sum(rep)
-            if plan.needs_snapshots:
-                if state.snap is None or state.glob is None:
-                    raise ValueError(
-                        "staleness='delay_compensated' carries per-group "
-                        "download snapshots in the state: build it with "
-                        "hfl_init(..., staleness_snapshots=True) "
-                        "(repro.api.build does this for you)")
-                # First-order delay compensation: shift a stale report by
-                # the global progress its group missed since it last
-                # downloaded (glob - snap_g; exactly zero for fresh groups).
-                xbar_used = jax.tree.map(
-                    lambda xj, gl, sn: xj + (jnp.expand_dims(gl, 0) - sn),
-                    xbar_j, state.glob, state.snap)
-            else:
-                xbar_used = xbar_j
+                xbar_j = jax.tree.map(lambda xi: xi[:, 0], x)   # [G, ...] (clients equal)
+                gup = jnp.float32(G)
+                if comp_g:
+                    gref = jax.tree.map(lambda xi: xi[:, 0], state.params)
+                    xbar_srv = xbar_j  # group server's own (pre-wire) aggregate
+                    xbar_j, ug, deqg = compress_group(xbar_j, gref, None)
+                xbar = tu.tree_mean(xbar_j, axis=0)             # [...]
+                gdrift = tu.tree_sq_norm(
+                    tu.tree_sub(xbar_j, tu.tree_broadcast_to_axis(xbar, 0, G))
+                ) / G
 
-            w = rep * dw                        # [G] deterministic weights
-            if partial and ht:
-                # Horvitz-Thompson over reachable groups composed with the
-                # deterministic report/policy weights: an empty reachable
-                # report contributes an exact zero while the denominator
-                # stays the expected reporting mass.
-                wsum = w * gmask
-                sup = wsum * gact
-                den = (gdenom / G) * jnp.sum(w)
-            elif masked:
-                wsum = w * gact
-                sup = wsum
-                den_raw = jnp.sum(wsum)
-                den = jnp.where(den_raw > 0, den_raw, 1.0)
-            else:
-                # >= 1 always: the pace-setting group (r_g = 1) reports
-                # every window at full weight.
-                wsum = w
-                sup = wsum
-                den = jnp.sum(w)
+            if ef_g:
+                # Gated on the FINAL estimation mask (post-timeout, post-
+                # screen): only a report that entered the merge advances the
+                # group's residual.
+                errg = tu.tree_sub(ug, deqg)
+                efg = tu.tree_select(gact, errg, efg) if masked else errg
 
-            def _stale_merge(v):
-                live = tu.expand_mask(sup, v) != 0
-                return jnp.sum(
-                    jnp.where(live, v, 0) * tu.expand_mask(wsum, v),
-                    axis=0) / den
+            # Group-global correction update (line 11):
+            #   y_j += (xbar_j^{t,E} - xbar^{t+1}) / (H * E * lr)
+            if use_y:
+                if async_mode:
+                    # Per report cycle: a reporting group ran E_g * r_g group
+                    # rounds since its last download. The policy discount dw
+                    # applies to the *merge* only -- y is a tracking estimator
+                    # and must update at full rate, or a transient y decays
+                    # geometrically (factor 1 - dw/G per report) and its bias
+                    # dominates the trajectory (see core/staleness.py).
+                    coef = 1.0 / (e_eff * H * lr)                         # [G]
+                    xbar_g = tu.tree_broadcast_to_axis(xbar, 0, G)
+                    y_new = jax.tree.map(
+                        lambda yj, xj, xg: yj + tu.expand_mask(coef, yj) * (xj - xg),
+                        y, xbar_used, xbar_g)
+                    y = tu.tree_select(obs, y_new, y)
+                else:
+                    # Like z above, y is group-server-side state: it updates
+                    # from the group's own aggregate (pre-wire), never from
+                    # the dequantized view carrying the EF residual.
+                    y_src = xbar_srv if comp_g else xbar_j
+                    y_new = jax.tree.map(
+                        lambda yj, xj, xg: yj + (xj - xg) / (H * E * lr), y, y_src, xbar
+                    )
+                    y = tu.tree_select(gact, y_new, y) if masked else y_new
 
-            xbar = jax.tree.map(_stale_merge, xbar_used)
-            gdrift = tu.tree_masked_sq_norm(
-                tu.tree_sub(xbar_j, tu.tree_broadcast_to_axis(xbar, 0, G)), obs
-            ) / jnp.maximum(jnp.sum(obs), 1.0)
-        elif masked and (fault_mode or defended or comp_g):
-            # The legacy recovery/estimation split of tree_group_global_mean,
-            # opened up so group-timeout faults, the group-level finite
-            # screen and group-link compression can compose into the
-            # estimation mask between the two stages (recovery over active
-            # replicas is unchanged).
-            xbar_j = tu.tree_masked_mean(x, cmask, axis=1)
-            gact = (jnp.sum(cmask, axis=1) > 0).astype(jnp.float32)
-            if f_timeout:
-                # A timed-out group misses the global exchange entirely:
-                # no upload, no y update, no download -- frozen this round.
-                gact = gact * tm_keep
-            gup = jnp.sum(gact)  # reports actually sent (pre-screen)
-            if comp_g:
-                # Compression happens at the upload, i.e. after the
-                # timeout composition (a timed-out group never sent bytes,
-                # so its residual must not advance) and before the finite
-                # screen (the backstop screens the dequantized report).
-                gref = tu.tree_masked_mean(state.params, cmask, axis=1)
-                xbar_srv = xbar_j  # group server's own (pre-wire) aggregate
-                xbar_j, ug, deqg = compress_group(xbar_j, gref, gact)
-            if defended and defense.screen_nonfinite:
-                gfin = _flt.all_finite_mask(xbar_j, 1)
-                screened = screened + jnp.sum(
-                    cmask * ((gact * (1.0 - gfin))[:, None]))
-                gact = gact * gfin
-            if ht:
-                xbar_j0 = jax.tree.map(
-                    lambda v: jnp.where(tu.expand_mask(gact, v) != 0, v, 0),
-                    xbar_j)
-                xbar = tu.tree_masked_mean(xbar_j0, gmask, axis=0,
-                                           denom=gdenom)
-            else:
-                xbar = tu.tree_masked_mean(xbar_j, gact, axis=0)
-            gdrift = tu.tree_masked_sq_norm(
-                tu.tree_sub(xbar_j, tu.tree_broadcast_to_axis(xbar, 0, G)), gact
-            ) / jnp.maximum(jnp.sum(gact), 1.0)
-        elif partial:
-            # A group with zero sampled clients never feeds the y update or
-            # dissemination of its own replicas (gact gating). Under
-            # realized-count weighting it is also renormalized out of the
-            # global mean; under inverse_prob every *reachable* group enters
-            # the Horvitz-Thompson sum, an empty one contributing zero --
-            # see tree_group_global_mean for the recovery/estimation split.
-            xbar_j, xbar, gact = tu.tree_group_global_mean(
-                x, cmask, gmask if ht else None, gdenom)
-            gup = jnp.sum(gact)
-            gdrift = tu.tree_masked_sq_norm(
-                tu.tree_sub(xbar_j, tu.tree_broadcast_to_axis(xbar, 0, G)), gact
-            ) / jnp.maximum(jnp.sum(gact), 1.0)
-        else:
-            xbar_j = jax.tree.map(lambda xi: xi[:, 0], x)   # [G, ...] (clients equal)
-            gup = jnp.float32(G)
-            if comp_g:
-                gref = jax.tree.map(lambda xi: xi[:, 0], state.params)
-                xbar_srv = xbar_j  # group server's own (pre-wire) aggregate
-                xbar_j, ug, deqg = compress_group(xbar_j, gref, None)
-            xbar = tu.tree_mean(xbar_j, axis=0)             # [...]
-            gdrift = tu.tree_sq_norm(
-                tu.tree_sub(xbar_j, tu.tree_broadcast_to_axis(xbar, 0, G))
-            ) / G
-
-        if ef_g:
-            # Gated on the FINAL estimation mask (post-timeout, post-
-            # screen): only a report that entered the merge advances the
-            # group's residual.
-            errg = tu.tree_sub(ug, deqg)
-            efg = tu.tree_select(gact, errg, efg) if masked else errg
-
-        # Group-global correction update (line 11):
-        #   y_j += (xbar_j^{t,E} - xbar^{t+1}) / (H * E * lr)
-        if use_y:
-            if async_mode:
-                # Per report cycle: a reporting group ran E_g * r_g group
-                # rounds since its last download. The policy discount dw
-                # applies to the *merge* only -- y is a tracking estimator
-                # and must update at full rate, or a transient y decays
-                # geometrically (factor 1 - dw/G per report) and its bias
-                # dominates the trajectory (see core/staleness.py).
-                coef = 1.0 / (e_eff * H * lr)                         # [G]
-                xbar_g = tu.tree_broadcast_to_axis(xbar, 0, G)
-                y_new = jax.tree.map(
-                    lambda yj, xj, xg: yj + tu.expand_mask(coef, yj) * (xj - xg),
-                    y, xbar_used, xbar_g)
-                y = tu.tree_select(obs, y_new, y)
-            else:
-                # Like z above, y is group-server-side state: it updates
-                # from the group's own aggregate (pre-wire), never from
-                # the dequantized view carrying the EF residual.
-                y_src = xbar_srv if comp_g else xbar_j
-                y_new = jax.tree.map(
-                    lambda yj, xj, xg: yj + (xj - xg) / (H * E * lr), y, y_src, xbar
+            # FedDyn gradient-memory update (per client, after its local work).
+            if use_dyn:
+                dyn_new = jax.tree.map(
+                    lambda mi, xi, ai: mi - cfg.feddyn_alpha * (xi - ai), dyn, x, anchor
                 )
-                y = tu.tree_select(gact, y_new, y) if masked else y_new
+                dyn = tu.tree_select(cmask, dyn_new, dyn) if masked else dyn_new
 
-        # FedDyn gradient-memory update (per client, after its local work).
-        if use_dyn:
-            dyn_new = jax.tree.map(
-                lambda mi, xi, ai: mi - cfg.feddyn_alpha * (xi - ai), dyn, x, anchor
+            # Dissemination: active clients restart from the (server-lr) global
+            # model; frozen clients keep what they have.
+            if cfg.server_lr != 1.0:
+                if partial:
+                    # No stored global model under partial participation: anchor
+                    # the server step on the mean over all replicas.
+                    prev = tu.tree_mean(state.params, axis=(0, 1))
+                else:
+                    prev = jax.tree.map(lambda xi: xi[0, 0], state.params)
+                xbar = jax.tree.map(lambda p, xb: p + cfg.server_lr * (xb - p), prev, xbar)
+            x_glob = jax.tree.map(
+                lambda xg: jnp.broadcast_to(xg, (G, K) + xg.shape), xbar
             )
-            dyn = tu.tree_select(cmask, dyn_new, dyn) if masked else dyn_new
-
-        # Dissemination: active clients restart from the (server-lr) global
-        # model; frozen clients keep what they have.
-        if cfg.server_lr != 1.0:
-            if partial:
-                # No stored global model under partial participation: anchor
-                # the server step on the mean over all replicas.
-                prev = tu.tree_mean(state.params, axis=(0, 1))
+            if async_mode:
+                if fault_mode or defended:
+                    # Reporting groups download only when the window actually
+                    # aggregated something: with the defense decoupling "has
+                    # active clients" from "entered the merge", a window whose
+                    # every report was screened must not disseminate its
+                    # hardened (exact-zero) merge.
+                    any_obs = (jnp.sum(obs) > 0).astype(jnp.float32)
+                    dmask = rep[:, None] * cmask * any_obs
+                elif masked:
+                    # Only reporting groups download; stragglers keep their
+                    # mid-cycle replicas (that lag is exactly what makes their
+                    # next report stale).
+                    dmask = rep[:, None] * cmask
+                else:
+                    dmask = jnp.broadcast_to(rep[:, None], (G, K))
+                x = tu.tree_select(dmask, x_glob, x)
             else:
-                prev = jax.tree.map(lambda xi: xi[0, 0], state.params)
-            xbar = jax.tree.map(lambda p, xb: p + cfg.server_lr * (xb - p), prev, xbar)
-        x_glob = jax.tree.map(
-            lambda xg: jnp.broadcast_to(xg, (G, K) + xg.shape), xbar
-        )
-        if async_mode:
-            if fault_mode or defended:
-                # Reporting groups download only when the window actually
-                # aggregated something: with the defense decoupling "has
-                # active clients" from "entered the merge", a window whose
-                # every report was screened must not disseminate its
-                # hardened (exact-zero) merge.
+                if fault_mode or defended:
+                    # Timed-out groups miss the download too (frozen), and no
+                    # one downloads a global mean with zero surviving groups.
+                    any_g = (jnp.sum(gact) > 0).astype(jnp.float32)
+                    dm = cmask * any_g
+                    if f_timeout:
+                        dm = dm * tm_keep[:, None]
+                    x = tu.tree_select(dm, x_glob, x)
+                elif masked:
+                    x = tu.tree_select(cmask, x_glob, x)
+                else:
+                    x = x_glob
+
+            snap, glob = state.snap, state.glob
+            if async_mode and plan.needs_snapshots:
+                # Reporting groups record the global model they just
+                # downloaded; the server records it as the latest global
+                # (guarded: a window where every reporter came up empty under
+                # partial participation aggregates nothing).
                 any_obs = (jnp.sum(obs) > 0).astype(jnp.float32)
-                dmask = rep[:, None] * cmask * any_obs
-            elif masked:
-                # Only reporting groups download; stragglers keep their
-                # mid-cycle replicas (that lag is exactly what makes their
-                # next report stale).
-                dmask = rep[:, None] * cmask
-            else:
-                dmask = jnp.broadcast_to(rep[:, None], (G, K))
-            x = tu.tree_select(dmask, x_glob, x)
-        else:
-            if fault_mode or defended:
-                # Timed-out groups miss the download too (frozen), and no
-                # one downloads a global mean with zero surviving groups.
-                any_g = (jnp.sum(gact) > 0).astype(jnp.float32)
-                dm = cmask * any_g
-                if f_timeout:
-                    dm = dm * tm_keep[:, None]
-                x = tu.tree_select(dm, x_glob, x)
-            elif masked:
-                x = tu.tree_select(cmask, x_glob, x)
-            else:
-                x = x_glob
+                snap = tu.tree_select(
+                    obs, tu.tree_broadcast_to_axis(xbar, 0, G), snap)
+                glob = tu.tree_select(any_obs, xbar, glob)
 
-        snap, glob = state.snap, state.glob
-        if async_mode and plan.needs_snapshots:
-            # Reporting groups record the global model they just
-            # downloaded; the server records it as the latest global
-            # (guarded: a window where every reporter came up empty under
-            # partial participation aggregates nothing).
-            any_obs = (jnp.sum(obs) > 0).astype(jnp.float32)
-            snap = tu.tree_select(
-                obs, tu.tree_broadcast_to_axis(xbar, 0, G), snap)
-            glob = tu.tree_select(any_obs, xbar, glob)
-
-        dl = state.dl
-        if async_mode and f_timeout:
-            # Realized downloads this window (rep already excludes timed-out
-            # groups): next round's freshness for the z re-init.
-            dl = rep * any_obs
+            dl = state.dl
+            if async_mode and f_timeout:
+                # Realized downloads this window (rep already excludes timed-out
+                # groups): next round's freshness for the z re-init.
+                dl = rep * any_obs
 
         # Bytes on the wire: every upload actually sent this round counts
         # (screened uploads spent their bytes; crashed/unsampled clients

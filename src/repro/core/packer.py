@@ -69,31 +69,35 @@ class Packer:
         return sum(n for _, n in self.buffer_sizes)
 
     def flatten(self, tree: PyTree) -> "FlatBuffers":
-        """Pack ``tree`` (template structure + arbitrary leading axes)."""
+        """Pack ``tree`` (template structure + arbitrary leading axes).
+
+        Traced under the ``state_repack`` scope, as is :meth:`unflatten`."""
         leaves = self.treedef.flatten_up_to(tree)
         lead = None
         parts: dict[str, list[jax.Array]] = {key: [] for key, _ in self.buffer_sizes}
-        for seg, leaf in zip(self.segments, leaves):
-            nlead = leaf.ndim - len(seg.shape)
-            if lead is None:
-                lead = leaf.shape[:nlead]
-            parts[seg.buffer].append(leaf.reshape(lead + (seg.size,)))
-        bufs = {
-            key: (chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks, axis=-1))
-            for key, chunks in parts.items()
-        }
+        with jax.named_scope("state_repack"):
+            for seg, leaf in zip(self.segments, leaves):
+                nlead = leaf.ndim - len(seg.shape)
+                if lead is None:
+                    lead = leaf.shape[:nlead]
+                parts[seg.buffer].append(leaf.reshape(lead + (seg.size,)))
+            bufs = {
+                key: (chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks, axis=-1))
+                for key, chunks in parts.items()
+            }
         return FlatBuffers(bufs, self)
 
     def unflatten(self, flat: "FlatBuffers | dict[str, jax.Array]") -> PyTree:
         """Rebuild the template-structured tree (leading axes preserved)."""
         bufs = flat.bufs if isinstance(flat, FlatBuffers) else flat
         leaves = []
-        for seg in self.segments:
-            buf = bufs[seg.buffer]
-            lead = buf.shape[:-1]
-            leaves.append(
-                buf[..., seg.offset:seg.offset + seg.size].reshape(lead + seg.shape)
-            )
+        with jax.named_scope("state_repack"):
+            for seg in self.segments:
+                buf = bufs[seg.buffer]
+                lead = buf.shape[:-1]
+                leaves.append(
+                    buf[..., seg.offset:seg.offset + seg.size].reshape(lead + seg.shape)
+                )
         return self.treedef.unflatten(leaves)
 
     def zeros(self, lead: tuple[int, ...] = ()) -> "FlatBuffers":

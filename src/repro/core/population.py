@@ -43,6 +43,7 @@ Front door: set ``ExperimentSpec.population`` / ``cohort_size`` /
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable
 
 import jax
@@ -178,14 +179,24 @@ class PopulationStore:
 
     # -------------------------------------------------- host <-> device
 
+    @functools.partial(jax.profiler.annotate_function,
+                       name="repro.population.gather")
     def gather(self, idx: np.ndarray) -> HostBuffers:
-        """Copy the cohort rows ``idx [G, K]`` out of the store (host)."""
+        """Copy the cohort rows ``idx [G, K]`` out of the store (host).
+
+        The host span ``repro.population.gather`` (and
+        ``repro.population.scatter`` on :meth:`scatter`) is for operators of
+        a population run, which no benchmark cell makes yet: a profiler
+        trace then shows how much of the host's copying the device's work
+        hides."""
         rows = np.arange(self.num_groups)[:, None]
         return {
             f: {key: buf[rows, idx] for key, buf in bufs.items()}
             for f, bufs in self.data.items()
         }
 
+    @functools.partial(jax.profiler.annotate_function,
+                       name="repro.population.scatter")
     def scatter(self, idx: np.ndarray, host_vals: HostBuffers) -> None:
         """Write the cohort rows back into the store, in place."""
         rows = np.arange(self.num_groups)[:, None]

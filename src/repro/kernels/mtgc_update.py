@@ -74,6 +74,7 @@ def mtgc_update(x, g, z, y, *, lr: float, g_scale: float = 1.0,
         out_specs=pl.BlockSpec((block_rows, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_p, LANE), dtype),
         interpret=interpret,
+        name="mtgc_update",
     )(*xs)
     return out.reshape(-1)[:n].reshape(shape)
 
@@ -142,5 +143,6 @@ def mtgc_update_flat(x, g, z, y, mask=None, *, lr: float, g_scale: float = 1.0,
         out_specs=pl.BlockSpec((1, br, LANE), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((G * K, rows_p, LANE), dtype),
         interpret=interpret,
+        name="mtgc_update_flat",
     )(*operands)
     return out.reshape(G * K, rows_p * LANE)[:, :n].reshape(G, K, n)

@@ -82,6 +82,7 @@ def int8_roundtrip(u, scale, noise, *, block_rows: int = DEFAULT_BLOCK_ROWS,
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((R, rows_p, LANE), dtype),
         interpret=interpret,
+        name="int8_roundtrip",
     )(us, ns, scale.reshape(R, 1, 1).astype(jnp.float32))
     return out.reshape(R, rows_p * LANE)[:, :n]
 
@@ -110,5 +111,6 @@ def topk_mask(u, thresh, *, block_rows: int = DEFAULT_BLOCK_ROWS,
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((R, rows_p, LANE), dtype),
         interpret=interpret,
+        name="topk_mask",
     )(us, thresh.reshape(R, 1, 1).astype(dtype))
     return out.reshape(R, rows_p * LANE)[:, :n]

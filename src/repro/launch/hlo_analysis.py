@@ -52,9 +52,12 @@ _BRANCHES_RE = re.compile(r"branch_computations=\{([^}]*)\}")
 _OPNAME_RE = re.compile(r'op_name="([^"]*)"')
 _FRAME_RE = re.compile(r"stack_frame_id=(\d+)")
 
-# semantic buckets from jax.named_scope tags planted in the model code
+# semantic buckets from jax.named_scope tags planted in the model code and
+# the MTGC engine (core/engine.py, core/packer.py); the chip benchmark's
+# trace reader attributes device time to the same names
 BUCKETS = ("attn", "moe", "mlp", "rwkv", "ssm", "xent", "embed",
-           "group_agg", "global_agg")
+           "group_agg", "global_agg", "client_step", "local_update",
+           "state_repack")
 
 # (file substring, function name) -> bucket; resolved through the HLO
 # stack-frame tables, which survive jvp/transpose/remat (named scopes don't).
