@@ -8,7 +8,7 @@ selection; the round function itself is driver-agnostic):
 
     for e in range(E):                 # lax.scan over group rounds
         for h in range(H):             # lax.scan over local steps
-            g_i   = grad F_i(x_i, xi)                  # vmapped over [G, K]
+            g_i   = grad F_i(x_i, xi)                  # all [G, K] at once
             x_i  -= lr * (g_i + z_i + y_j [+ prox/dyn terms])
         group aggregation + z update (Alg. 1, lines 8-9)
     global aggregation + y update     (Alg. 1, lines 10-11)
@@ -227,10 +227,27 @@ def hfl_init(params0: PyTree, cfg: HFLConfig, rng: jax.Array | None = None,
 
 
 def _client_grads(loss_fn: Callable, params: PyTree, batch: PyTree):
-    """(loss, grad) of the local loss, vmapped over the [G, K] leading axes."""
-    vg = jax.value_and_grad(loss_fn)
-    with jax.named_scope("client_step"):
-        return jax.vmap(jax.vmap(vg))(params, batch)
+    """(loss, grad) of the local loss over the [G, K] leading axes.
+
+    A loss with a client-packed form (``loss_fn.packed``, the conv models of
+    ``models/small.py``) runs every client in one step on the [G, K]-stacked
+    params and batch; the gradient of the sum of the clients' losses is
+    each client's own, since clients share no parameter. Any other loss is
+    vmapped over [G, K].
+    """
+    packed = getattr(loss_fn, "packed", None)
+    if packed is None:
+        vg = jax.value_and_grad(loss_fn)
+        with jax.named_scope("client_step"):
+            return jax.vmap(jax.vmap(vg))(params, batch)
+
+    def total(p, b):
+        losses = packed(p, b)
+        return jnp.sum(losses), losses
+
+    with jax.named_scope("client_step"), jax.named_scope("clients_packed"):
+        (_, loss), g = jax.value_and_grad(total, has_aux=True)(params, batch)
+    return loss, g
 
 
 def make_global_round(
@@ -246,9 +263,9 @@ def make_global_round(
         adapter, so both paths are the same program.
 
     ``loss_fn(params, batch) -> scalar`` is a single-client loss; the engine
-    vmaps it over the [G, K] axes. ``batches`` passed to the returned function
-    must have leaves shaped ``[E, H, G, K, ...]`` (one batch per local step
-    per client).
+    runs it over the [G, K] axes (``_client_grads``). ``batches`` passed to
+    the returned function must have leaves shaped ``[E, H, G, K, ...]`` (one
+    batch per local step per client).
 
     The returned function adapts at trace time to the state layout it is
     given: a flat state (from ``hfl_init`` under ``cfg.use_flat_state``)

@@ -7,11 +7,25 @@
 * LSTM: char-level LSTM (Shakespeare)
 
 Each factory returns ``(init_fn(rng) -> params, apply_fn(params, x) -> logits)``.
-Models are plain pytrees -- no framework dependency -- so the HFL engine's
-[G, K]-stacked vmapping works untouched.
+Models are plain pytrees -- no framework dependency -- so the HFL engine can
+stack them ``[G, K, ...]`` and run every client at once.
+
+The conv models (CNN, ResNet-GN) also carry a *client-packed* apply,
+``apply.packed(params, x)``: params stacked over leading client axes
+``lead`` (the engine's ``[G, K]``; C clients in all), inputs ``[*lead, B,
+...]``, logits ``[*lead, B, classes]``. Inside it the activations stay
+``[B, H, W, C*ch]`` (client-major within the channels) from input to head:
+each conv is one grouped conv over the C clients, and bias, GroupNorm,
+ReLU, pools and residual adds act on that layout as it is.
+``make_loss`` passes it on as ``loss.packed``, which the engine's client
+step (``core/engine.py`` ``_client_grads``) takes in place of vmapping the
+per-client loss over ``[G, K]``; vmap's per-op batching rules would move
+the client axis between the channels and the front at every op. The MLPs
+and the LSTM have no packed apply, and the engine vmaps them.
 """
 from __future__ import annotations
 
+import math
 from typing import Callable, Tuple
 
 import jax
@@ -89,6 +103,52 @@ def _apply_conv(p, x, stride=1, padding="SAME"):
     return y + p["b"]
 
 
+def _clients(v, per_client_ndim):
+    """``(lead, C)`` of a leaf stacked over leading client axes ``lead``."""
+    lead = v.shape[:v.ndim - per_client_ndim]
+    return lead, math.prod(lead)
+
+
+def _pack_input(x, lead, image_shape):
+    """``[*lead, B, ...]`` client inputs -> one ``[B, H, W, C*c]`` image batch,
+    each client's channels together (the one input-sized move)."""
+    h, w, c = image_shape
+    n = math.prod(lead)
+    bsz = x.shape[len(lead)]
+    x = jnp.moveaxis(x.reshape(n, bsz, h, w, c), 0, 3)
+    return x.reshape(bsz, h, w, n * c)
+
+
+def _apply_conv_packed(p, x, stride=1, padding="SAME"):
+    """Every client's conv as one grouped conv: ``x`` is ``[B, H, W, C*cin]``
+    and ``p`` is stacked over the clients; the output is ``[B, H', W',
+    C*cout]`` in the same client-major channel order."""
+    _, n = _clients(p["w"], 4)
+    kh, kw, cin, cout = p["w"].shape[-4:]
+    w = p["w"].reshape(n, kh, kw, cin, cout)
+    w = jnp.moveaxis(w, 0, 3).reshape(kh, kw, cin, n * cout)
+    y = jax.lax.conv_general_dilated(
+        x, w, (stride, stride), padding,
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        feature_group_count=n,
+    )
+    return y + p["b"].reshape(-1)
+
+
+def _dense_packed(p, x):
+    """Per-client dense layer: ``x`` ``[*lead, B, in]`` -> ``[*lead, B,
+    out]``, batched over the clients' own axes. Not over one merged ``[C,
+    out]`` axis: with a few classes the TPU compiler then lays the bias out
+    with the clients along the lanes, and copies the whole flat ``[G, K,
+    N]`` state into that tiling to slice it."""
+    return jnp.einsum("...bi,...io->...bo", x, p["w"]) + p["b"][..., None, :]
+
+
+def _max_pool(x):
+    return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 2, 2, 1),
+                                 (1, 2, 2, 1), "VALID")
+
+
 def cnn(num_classes: int, image_shape=(8, 8, 1)) -> Tuple[Init, Apply]:
     """McMahan-style CNN: conv5x32 - pool - conv5x64 - pool - fc512 - fc."""
     h, w, c = image_shape
@@ -106,13 +166,25 @@ def cnn(num_classes: int, image_shape=(8, 8, 1)) -> Tuple[Init, Apply]:
     def apply(p, x):
         x = x.reshape(x.shape[0], h, w, c)
         x = jax.nn.relu(_apply_conv(p["c1"], x))
-        x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+        x = _max_pool(x)
         x = jax.nn.relu(_apply_conv(p["c2"], x))
-        x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+        x = _max_pool(x)
         x = x.reshape(x.shape[0], -1)
         x = jax.nn.relu(x @ p["f1"]["w"] + p["f1"]["b"])
         return x @ p["out"]["w"] + p["out"]["b"]
 
+    def apply_packed(p, x):
+        lead, n = _clients(p["c1"]["b"], 1)
+        x = _pack_input(x, lead, image_shape)
+        x = _max_pool(jax.nn.relu(_apply_conv_packed(p["c1"], x)))
+        x = _max_pool(jax.nn.relu(_apply_conv_packed(p["c2"], x)))
+        # fc1 reads each client's (h, w, c) flatten: one move to [C, B, F].
+        bsz, hh, ww, nc = x.shape
+        x = jnp.moveaxis(x.reshape(bsz, hh, ww, n, nc // n), 3, 0)
+        x = jax.nn.relu(_dense_packed(p["f1"], x.reshape(lead + (bsz, -1))))
+        return _dense_packed(p["out"], x)
+
+    apply.packed = apply_packed
     return init, apply
 
 
@@ -125,6 +197,27 @@ def _groupnorm(p, x, groups):
     xg = (xg - mu) * jax.lax.rsqrt(var + 1e-5)
     x = xg.reshape(n, h, w, c)
     return x * p["scale"] + p["bias"]
+
+
+def _groupnorm_packed(p, x, groups):
+    """GroupNorm of each client on the packed ``[B, H, W, C*c]`` layout,
+    without reshaping the activation: statistics reduce over H and W first,
+    then over each group's channels on the small ``[B, C*c]`` array, and
+    broadcast back. The variance is two-pass, as ``jnp.var``'s: the mean of
+    (x - mu)^2."""
+    _, n = _clients(p["scale"], 1)
+    bsz, _, _, nc = x.shape
+    g = min(groups, nc // n)
+
+    def group_mean(t):            # [B, n*c] -> each channel its group's mean
+        t = t.reshape(bsz, n * g, -1)
+        t = jnp.broadcast_to(t.mean(axis=-1, keepdims=True), t.shape)
+        return t.reshape(bsz, nc)[:, None, None, :]
+
+    d = x - group_mean(x.mean(axis=(1, 2)))
+    var = group_mean(jax.lax.square(d).mean(axis=(1, 2)))
+    return (d * jax.lax.rsqrt(var + 1e-5) * p["scale"].reshape(-1)
+            + p["bias"].reshape(-1))
 
 
 def _gn_params(c):
@@ -179,6 +272,32 @@ def resnet_gn(
         x = x.mean(axis=(1, 2))
         return x @ p["out"]["w"] + p["out"]["b"]
 
+    def apply_packed(p, x):
+        lead, n = _clients(p["stem"]["b"], 1)
+
+        def conv_gn(pc, pg, x, stride=1):
+            return _groupnorm_packed(pg, _apply_conv_packed(pc, x, stride),
+                                     gn_groups)
+
+        x = jax.nn.relu(conv_gn(p["stem"], p["stem_gn"],
+                                _pack_input(x, lead, image_shape)))
+        for s in range(len(widths)):
+            for b in range(blocks_per_stage):
+                blk = p[f"s{s}b{b}"]
+                stride = 2 if (b == 0 and s > 0) else 1
+                y = jax.nn.relu(conv_gn(blk["c1"], blk["gn1"], x, stride))
+                y = conv_gn(blk["c2"], blk["gn2"], y)
+                sc = x if "proj" not in blk else _apply_conv_packed(
+                    blk["proj"], x, stride)
+                if stride != 1 and "proj" not in blk:
+                    sc = sc[:, ::2, ::2, :]
+                x = jax.nn.relu(y + sc)
+        bsz, nc = x.shape[0], x.shape[-1]
+        x = x.mean(axis=(1, 2)).reshape(bsz, n, nc // n)
+        return _dense_packed(p["out"], jnp.moveaxis(x, 0, 1).reshape(
+            lead + (bsz, -1)))
+
+    apply.packed = apply_packed
     return init, apply
 
 
@@ -222,12 +341,25 @@ def softmax_xent(logits: jax.Array, labels: jax.Array) -> jax.Array:
 
 
 def make_loss(apply: Apply) -> Callable[[dict, dict], jax.Array]:
-    """Standard classification / next-token loss over {'x','y'} batches."""
+    """Standard classification / next-token loss over {'x','y'} batches.
+
+    Where ``apply`` has a client-packed form (``apply.packed``), the loss
+    carries one too: ``loss.packed(params, batch)`` takes params stacked
+    over leading client axes ``lead`` and batch leaves ``[*lead, B, ...]``,
+    and returns each client's mean loss, ``[*lead]``."""
 
     def loss(params, batch):
         logits = apply(params, batch["x"])
         return softmax_xent(logits, batch["y"])
 
+    packed = getattr(apply, "packed", None)
+    if packed is not None:
+        def packed_loss(params, batch):
+            logp = jax.nn.log_softmax(packed(params, batch["x"]), axis=-1)
+            nll = -jnp.take_along_axis(logp, batch["y"][..., None], axis=-1)
+            return nll.mean(axis=(-2, -1))
+
+        loss.packed = packed_loss
     return loss
 
 
