@@ -1,20 +1,28 @@
 """The one traffic generator: a federation's data, split and batches from a seed.
 
 A traffic mix is a JSON file in ``bench/traffic/`` (see ``full.json``); this
-module reads its parameters and builds everything a run feeds the program:
+module reads its parameters and builds everything a run feeds the program.
+What kind of data depends on the configuration's ``task``:
 
-* synthetic CIFAR-shaped classification data (a Gaussian mixture over class
-  prototypes, flat ``[n, H*W*C]`` float32 rows);
-* the label-skewed split over groups and clients (Dirichlet, as in the
-  paper's Sec. 5.1);
-* which rows each client's packed shards hold, and which shard each group
-  round draws.
+* classification (no ``task``): synthetic CIFAR-shaped data (a Gaussian
+  mixture over class prototypes, flat ``[n, H*W*C]`` float32 rows) and the
+  label-skewed split over groups and clients (Dirichlet, as in the paper's
+  Sec. 5.1);
+* ``causal_lm``: one token stream per client, of documents of
+  heavy-tailed length (lognormal), each drawn from one domain's unigram
+  distribution over the vocabulary and ended by an end-of-document id;
+  the domain mixtures are skewed at both levels (``both_noniid``: each
+  group's from Dir(alpha), each client's from Dir(alpha) around its
+  group's), the two-level drift that MTGC corrects;
+* which rows (or token windows) each client's packed shards hold, and which
+  shard each group round draws.
 
 ``make_classification`` and ``partition`` are copies of
-``repro.data.synthetic`` and ``repro.data.partition``, so a change to the
-program cannot change the traffic. ``shard_rows`` and ``round_shards``
-repeat the draws of the program's packer (``pack_client_shards``) and of
-its on-device batch selection (``select_round``), so that the plain
+``repro.data.synthetic`` and ``repro.data.partition``, and the domains
+follow ``repro.data.lm``, so a change to the program cannot change the
+traffic. ``shard_rows``, ``token_windows`` and ``round_shards`` repeat the
+draws of the program's packers (``pack_client_shards``, ``pack_lm_shards``)
+and of its on-device batch selection (``select_round``), so that the plain
 reference sees the same batches as the program without reading anything the
 program made.
 """
@@ -118,8 +126,21 @@ class Federation:
     indices: list[list[np.ndarray]]  # [G][K] row indices
 
 
-def make_federation(cfg: dict, traffic: dict, seed: int) -> Federation:
+@dataclasses.dataclass
+class TokenFederation:
+    """One run's token data: each client's stream and the domain mixtures
+    it was drawn from."""
+
+    streams: list[list[np.ndarray]]  # [G][K] int32 token streams
+    group_mix: np.ndarray            # [G, D] each group's domain mixture
+    client_mix: np.ndarray           # [G, K, D] each client's
+
+
+def make_federation(cfg: dict, traffic: dict,
+                    seed: int) -> Federation | TokenFederation:
     """The data and split of one run of ``traffic`` on configuration ``cfg``."""
+    if cfg.get("task") == "causal_lm":
+        return make_token_federation(cfg, traffic, seed)
     G, K = cfg["levels"]
     n = traffic["samples_per_client"] * G * K
     dim = int(np.prod(cfg["image_shape"]))
@@ -131,8 +152,78 @@ def make_federation(cfg: dict, traffic: dict, seed: int) -> Federation:
     return Federation(x, y, idx)
 
 
+def domain_mixtures(rng: np.random.Generator, groups: int, clients: int,
+                    domains: int, alpha: float) -> tuple[np.ndarray,
+                                                        np.ndarray]:
+    """``(group_mix [G, D], client_mix [G, K, D])`` of a ``both_noniid``
+    split: each group's mixture from Dir(alpha), each client's from
+    Dir(alpha * D * its group's mixture), whose mean is the group's and
+    whose concentration is the group level's. Parameters under 1e-3 are
+    raised to 1e-3, so that the draw stays defined."""
+    group = rng.dirichlet(alpha * np.ones(domains), size=groups)
+    client = np.stack([
+        rng.dirichlet(np.maximum(alpha * domains * gm, 1e-3), size=clients)
+        for gm in group])
+    return group, client
+
+
+def document_stream(rng: np.random.Generator, protos: np.ndarray,
+                    mix: np.ndarray, length: int, median: float,
+                    sigma: float, eod: int) -> np.ndarray:
+    """``length`` tokens of documents, each ended by ``eod``.
+
+    A document's length (its ``eod`` included) is lognormal with median
+    ``median`` and shape ``sigma``, at least 2; its domain is drawn from
+    ``mix`` and its tokens from that domain's unigram row of ``protos``.
+    The last document is cut where the stream ends.
+    """
+    lens, total = [], 0
+    while total < length:
+        n = max(2, int(round(rng.lognormal(np.log(median), sigma))))
+        lens.append(n)
+        total += n
+    lens = np.asarray(lens)
+    doms = rng.choice(len(mix), size=len(lens), p=mix)
+    ends = np.cumsum(lens)
+    owner = np.repeat(np.arange(len(lens)), lens)   # document of each slot
+    out = np.empty(int(ends[-1]), np.int32)
+    for d in np.unique(doms):
+        slots = np.flatnonzero(doms[owner] == d)
+        out[slots] = rng.choice(protos.shape[1], size=len(slots), p=protos[d])
+    out[ends - 1] = eod
+    return out[:length]
+
+
+def make_token_federation(cfg: dict, traffic: dict,
+                          seed: int) -> TokenFederation:
+    """Per-client token streams of ``traffic`` on a ``causal_lm``
+    configuration: ``tokens_per_client`` tokens each, over the
+    configuration's ``vocab_size``, ended by its ``eos_token_id`` (the
+    vocabulary's last id where it states none)."""
+    G, K = cfg["levels"]
+    vocab = cfg["vocab_size"]
+    eod = int(cfg.get("eos_token_id", vocab - 1))
+    if not 0 <= eod < vocab:
+        raise ValueError(f"eos_token_id {eod} outside the vocabulary "
+                         f"of {vocab}")
+    if traffic["partition"] != "both_noniid":
+        raise ValueError(f"unknown token partition {traffic['partition']!r}")
+    data = np.random.default_rng(stream(seed, "data"))
+    protos = data.dirichlet(0.05 * np.ones(vocab), size=traffic["domains"])
+    group_mix, client_mix = domain_mixtures(
+        np.random.default_rng(stream(seed, "partition")), G, K,
+        traffic["domains"], traffic["alpha"])
+    streams = [[document_stream(data, protos, client_mix[g, k],
+                                traffic["tokens_per_client"],
+                                traffic["doc_len_median"],
+                                traffic["doc_len_sigma"], eod)
+                for k in range(K)] for g in range(G)]
+    return TokenFederation(streams, group_mix, client_mix)
+
+
 def pack_rng(seed: int) -> np.random.Generator:
-    """The generator handed to the program's packer (``pack_arrays``)."""
+    """The generator handed to the program's packer (``pack_arrays`` or
+    ``pack_tokens``)."""
     return np.random.default_rng(stream(seed, "pack"))
 
 
@@ -148,6 +239,52 @@ def shard_rows(indices: list[list[np.ndarray]], shards: int, steps: int,
     return np.stack([np.stack([rng.choice(pool, size=(shards, steps, batch),
                                           replace=True) for pool in group])
                      for group in indices])
+
+
+def token_windows(streams: list[list[np.ndarray]], shards: int, steps: int,
+                  batch: int, seq_len: int,
+                  rng: np.random.Generator) -> dict[str, np.ndarray]:
+    """``{"tokens", "targets"}``, ``[G, K, S, steps, B, seq_len]`` int32:
+    the windows of every client's packed shards.
+
+    The draw of the program's ``pack_lm_shards`` on per-client streams:
+    clients in row-major order, each ``shards x steps x batch`` window
+    starts uniform over its stream, the targets the tokens shifted by one.
+    Given a generator in the state that ``pack_rng`` returns, it names the
+    windows the packer put in each slot.
+    """
+    toks, targs = [], []
+    for group in streams:
+        tg, yg = [], []
+        for s in group:
+            starts = rng.integers(0, len(s) - seq_len - 1,
+                                  size=(shards, steps, batch))
+            win = starts[..., None] + np.arange(seq_len)
+            tg.append(s[win].astype(np.int32))
+            yg.append(s[win + 1].astype(np.int32))
+        toks.append(tg)
+        targs.append(yg)
+    return {"tokens": np.asarray(toks), "targets": np.asarray(targs)}
+
+
+def packed_slots(fed: Federation | TokenFederation, traffic: dict,
+                 steps: int, rng: np.random.Generator
+                 ) -> tuple[dict[str, np.ndarray], np.ndarray]:
+    """``(arrays, rows)``: ``arrays[name][rows]`` is what the program's
+    packer put in each slot, ``rows`` being ``[G, K, S, steps, B]``.
+
+    Classification: the federation's rows and ``shard_rows``' table.
+    Tokens: ``token_windows``, one row per window.
+    """
+    if isinstance(fed, TokenFederation):
+        win = token_windows(fed.streams, traffic["shards"], steps,
+                            traffic["batch"], traffic["seq_len"], rng)
+        lead = win["tokens"].shape[:5]
+        rows = np.arange(int(np.prod(lead))).reshape(lead)
+        return {k: v.reshape((-1,) + v.shape[5:]) for k, v in win.items()}, rows
+    rows = shard_rows(fed.indices, traffic["shards"], steps, traffic["batch"],
+                      rng)
+    return {"x": fed.x, "y": fed.y}, rows
 
 
 def round_shards(data_key: jax.Array, rounds: int, group_rounds: int,
@@ -166,16 +303,21 @@ def round_shards(data_key: jax.Array, rounds: int, group_rounds: int,
     return np.stack(out)
 
 
-def round_batches(fed: Federation, rows: np.ndarray,
-                  sids: np.ndarray) -> dict[str, np.ndarray]:
-    """One global round's batches, ``[E, H, G, K, B, ...]``, on the host.
+def round_batches(arrays: dict[str, np.ndarray], rows: np.ndarray,
+                  sids: np.ndarray,
+                  microbatches: int = 1) -> dict[str, np.ndarray]:
+    """One global round's batches of every named array, ``[E, H, G, K, A,
+    B, ...]``, on the host.
 
-    ``rows`` is ``shard_rows``' table and ``sids`` one round of
-    ``round_shards``.
+    ``arrays`` and ``rows`` are ``packed_slots``' and ``sids`` one round of
+    ``round_shards``. A shard's ``steps = H * A`` step-batches are local
+    step h's A microbatches in order, as ``select_round`` reads them.
     """
     G, K = rows.shape[:2]
     g = np.arange(G)[None, :, None]
     k = np.arange(K)[None, None, :]
-    sel = rows[g, k, sids]                       # [E, G, K, H, B]
-    sel = np.moveaxis(sel, 3, 1)                 # [E, H, G, K, B]
-    return {"x": fed.x[sel], "y": fed.y[sel]}
+    sel = rows[g, k, sids]                       # [E, G, K, H*A, B]
+    E, _, _, steps, B = sel.shape
+    sel = sel.reshape(E, G, K, steps // microbatches, microbatches, B)
+    sel = np.moveaxis(sel, 3, 1)                 # [E, H, G, K, A, B]
+    return {name: arr[sel] for name, arr in arrays.items()}

@@ -11,11 +11,19 @@ of G groups x K clients:
         xbar_j = mean_k x_i;   z_i <- z_i + (x_i - xbar_j) / (H lr);   x_i <- xbar_j
     xbar = mean_j xbar_j;      y_j <- y_j + (xbar_j - xbar) / (H E lr);  x_i <- xbar
 
-The clients' gradients run group by group (``lax.map`` over groups, ``vmap``
-over a group's clients), so the reference needs about a G-th of the
-activations the program holds. ``dtype`` is the precision everything is
-held and computed in; float32 runs under ``default_matmul_precision
-(precision)``: ``"default"``, the precision a configuration states (one
+A client's local step takes the gradient of the configuration's loss
+(``loss(params, batch)``, a mean over the batch's rows) over its A
+microbatches of B rows: the mean of the A microbatch gradients, which is
+the gradient of the mean over the A*B rows. Classification merges the
+microbatches into one batch and runs the clients group by group
+(``lax.map`` over groups, ``vmap`` over a group's clients), so the
+reference needs about a G-th of the activations the program holds. With
+``per_client`` (causal LMs) it maps over the clients one at a time and over
+each client's microbatches, so it holds one microbatch of one client's
+activations.
+
+``dtype`` is the precision everything is held and computed in; float32
+runs under ``default_matmul_precision(precision)``: ``"default"``, the precision a configuration states (one
 bfloat16 pass per product on a TPU, float32 on a CPU), or ``"highest"``
 (float32 products on a TPU), a second reading for the calibration. Two
 planted faults, for the calibration of the limits: ``batch_fraction < 1``
@@ -32,31 +40,47 @@ import jax.numpy as jnp
 import numpy as np
 
 
+BACKENDS = ("simulator", "sharded")
+SCHEDULE = frozenset({"group_rounds", "local_steps", "microbatches"})
+
+
 def supports(spec: dict) -> bool:
-    """Whether the reference implements a traffic mix's ``spec`` section."""
-    plain = {"algorithm", "lr", "schedule"}
-    return spec.get("algorithm") == "mtgc" and set(spec) <= plain
+    """Whether the reference implements a traffic mix's ``spec`` section:
+    MTGC at full participation with uncompressed uploads, on either
+    two-level backend, with a uniform schedule; microbatches (the sharded
+    backend's gradient accumulation) change no arithmetic of the round."""
+    plain = {"algorithm", "lr", "schedule", "backend"}
+    backend = spec.get("backend", "simulator")
+    sched = spec.get("schedule", {})
+    return (spec.get("algorithm") == "mtgc" and set(spec) <= plain
+            and backend in BACKENDS and set(sched) <= SCHEDULE
+            and isinstance(sched.get("group_rounds", 1), int)
+            and (sched.get("microbatches") is None or backend == "sharded"))
 
 
-def _loss(forward: Callable, p, x, y):
-    logits = forward(p, x)
+def classification_loss(forward: Callable, p, batch: dict):
+    """Softmax cross-entropy of ``forward(p, x)`` against the labels ``y``,
+    the mean over the rows."""
+    logits = forward(p, batch["x"])
     logp = jax.nn.log_softmax(logits, axis=-1)
-    return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=-1))
+    return -jnp.mean(jnp.take_along_axis(logp, batch["y"][:, None], axis=-1))
 
 
-def run_rounds(forward: Callable, params0: dict, batches: list[dict], *,
+def run_rounds(loss: Callable, params0: dict, batches: list[dict], *,
                levels: tuple[int, int], lr: float, group_rounds: int,
-               local_steps: int, dtype=jnp.float32,
-               precision: str = "default",
+               local_steps: int, per_client: bool = False,
+               dtype=jnp.float32, precision: str = "default",
                batch_fraction: float = 1.0, drop_y: bool = False
                ) -> tuple[np.ndarray, list[dict], list[dict]]:
     """Train ``len(batches)`` global rounds from ``params0``.
 
-    ``batches[r]`` holds round r's ``{"x": [E, H, G, K, B, D], "y": [E, H,
-    G, K, B]}`` host arrays. Returns the mean client loss of every local
-    step, ``[R, E, H]`` float64, the global model after every round as
-    host float64 trees, and after every round the norm of each leaf of the
-    corrections z (of the round's last group round) and y.
+    ``batches[r]`` holds round r's named host arrays, each ``[E, H, G, K,
+    A, B, ...]`` (A microbatches of B rows a local step). ``loss(params,
+    batch)`` is one client's loss on one batch of rows. Returns the mean
+    client loss of every local step, ``[R, E, H]`` float64, the global
+    model after every round as host float64 trees, and after every round
+    the norm of each leaf of the corrections z (of the round's last group
+    round) and y.
     """
     G, K = levels
     E, H = group_rounds, local_steps
@@ -64,28 +88,58 @@ def run_rounds(forward: Callable, params0: dict, batches: list[dict], *,
     precision = (jax.default_matmul_precision(precision)
                  if dt == jnp.float32 else contextlib.nullcontext())
     cast = lambda t: jax.tree.map(lambda a: jnp.asarray(a, dt), t)
+    # Inputs in the run's precision; labels and token ids as they are.
+    cast_in = lambda b: {k: a.astype(dt) if jnp.issubdtype(a.dtype,
+                                                            jnp.floating)
+                         else a for k, a in b.items()}
 
-    def client(p, z, y, bx, by):
-        loss, g = jax.value_and_grad(lambda q: _loss(forward, q, bx, by))(p)
+    def grad_of(p, b):
+        """(loss, gradient) of one client: the mean over its microbatches
+        (leading axis of ``b``)."""
+        def micro(acc, bm):
+            lv, g = jax.value_and_grad(loss)(p, bm)
+            return (acc[0] + lv.astype(jnp.float32),
+                    jax.tree.map(jnp.add, acc[1], g)), None
+
+        A = jax.tree.leaves(b)[0].shape[0]
+        init = (jnp.zeros((), jnp.float32), jax.tree.map(jnp.zeros_like, p))
+        (lsum, gsum), _ = jax.lax.scan(micro, init, b)
+        return lsum / A, jax.tree.map(lambda g: g / A, gsum)
+
+    def client(p, z, y, b):
+        if per_client:
+            lv, g = grad_of(p, b)
+        else:
+            lv, g = jax.value_and_grad(loss)(p, b)
         if drop_y:
             y = jax.tree.map(jnp.zeros_like, y)
         new = jax.tree.map(lambda pi, gi, zi, yi: pi - lr * (gi + zi + yi),
                            p, g, z, y)
-        return new, loss
+        return new, lv
 
     @jax.jit
-    def local_step(x, z, y, bx, by):
+    def local_step(x, z, y, b):
         if batch_fraction < 1.0:
-            keep = max(1, int(bx.shape[2] * batch_fraction))
-            bx, by = bx[:, :, :keep], by[:, :, :keep]
+            keep = max(1, int(jax.tree.leaves(b)[0].shape[3] * batch_fraction))
+            b = {k: a[:, :, :, :keep] for k, a in b.items()}
+        b = cast_in(b)
+        if per_client:
+            def group(args):
+                xg, zg, yg, bg = args
+                return jax.lax.map(lambda a: client(a[0], a[1], yg, a[2]),
+                                   (xg, zg, bg))
+        else:
+            # The microbatches merged: [G, K, A*B, ...].
+            b = {k: a.reshape(a.shape[:2] + (-1,) + a.shape[4:])
+                 for k, a in b.items()}
 
-        def group(args):
-            xg, zg, yg, bxg, byg = args
-            return jax.vmap(client, in_axes=(0, 0, None, 0, 0))(
-                xg, zg, yg, bxg, byg)
+            def group(args):
+                xg, zg, yg, bg = args
+                return jax.vmap(client, in_axes=(0, 0, None, 0))(
+                    xg, zg, yg, bg)
 
-        x, loss = jax.lax.map(group, (x, z, y, bx.astype(dt), by))
-        return x, jnp.mean(loss.astype(jnp.float32))
+        x, lv = jax.lax.map(group, (x, z, y, b))
+        return x, jnp.mean(lv.astype(jnp.float32))
 
     @jax.jit
     def group_aggregate(x, z):
@@ -114,11 +168,11 @@ def run_rounds(forward: Callable, params0: dict, batches: list[dict], *,
             z = jax.tree.map(jnp.zeros_like, x)
             for e in range(E):
                 for h in range(H):
-                    x, loss = local_step(x, z, y, jnp.asarray(rb["x"][e, h]),
-                                         jnp.asarray(rb["y"][e, h]))
+                    x, lv = local_step(x, z, y, {
+                        k: jnp.asarray(a[e, h]) for k, a in rb.items()})
                     # One step at a time: a loop that runs ahead holds
                     # every queued step's new replicas on the device.
-                    losses.append(float(loss))
+                    losses.append(float(lv))
                 x, z = group_aggregate(x, z)
             x, y, xbar = global_aggregate(x, y)
             models.append(jax.tree.map(

@@ -10,7 +10,8 @@ names a configuration and a traffic mix; the run
 2. sets up: makes the data from the seed (``feed.py``), the weights on the
    device in one jitted call (the configuration's ``reference.py``), builds
    the engine through ``repro.api.build`` and packs the data with the
-   engine's ``pack_arrays``;
+   engine's ``pack_arrays`` (classification) or ``pack_tokens`` (a
+   configuration whose ``task`` is ``causal_lm``);
 3. drives the engine's first ``checked_calls`` ``fit`` calls, the first of
    which compiles, and keeps what the comparison needs from them;
 4. measures: repeats ``fit`` calls of ``rounds_per_call`` rounds, each
@@ -45,6 +46,7 @@ from typing import Any, NamedTuple  # noqa: E402
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 TRACE_DIR = ROOT / ".bench_trace"
+CACHE_DIR = ROOT / ".jax_cache"
 
 
 class NoAccelerator(RuntimeError):
@@ -71,25 +73,43 @@ class Cell:
         self.workload = workload
         self.config = config
         self.model = model            # the program's model and FLOP count
-        self.ref = ref                # the plain forward pass and weights
+        self.ref = ref                # the plain reference and weights
         self.traffic = traffic
         self.limits = limits
         self.per_layer = per_layer    # [(name, unit, reader module)]
 
+    @property
+    def causal_lm(self) -> bool:
+        """Whether the configuration is a causal LM trained on token
+        streams (``"task": "causal_lm"``); else it classifies rows."""
+        return self.config.get("task") == "causal_lm"
 
-def load_cell(name: str, benchmark: dict | None = None) -> Cell:
-    """Resolve workload ``name`` of ``BENCHMARK.json`` to its files."""
+    @property
+    def microbatches(self) -> int:
+        """A, the microbatches of a local step: the sharded backend's
+        ``schedule.microbatches`` (1 where unset), 1 elsewhere."""
+        spec = self.traffic["spec"]
+        if spec.get("backend", "simulator") != "sharded":
+            return 1
+        return spec["schedule"].get("microbatches") or 1
+
+
+def load_cell(name: str, benchmark: dict | None = None,
+              root: Path = ROOT) -> Cell:
+    """Resolve workload ``name`` of ``BENCHMARK.json`` to its files, in the
+    checkout at ``root``."""
+    bench = root / "bench"
     if benchmark is None:
-        benchmark = json.loads((ROOT / "BENCHMARK.json").read_text())
+        benchmark = json.loads((root / "BENCHMARK.json").read_text())
     wl = {w["name"]: w for w in benchmark["workloads"]}.get(name)
     if wl is None:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
     cfg_entry = {c["name"]: c for c in benchmark["configs"]}[wl["config"]]
-    cfg_file = ROOT / cfg_entry["file"]
+    cfg_file = root / cfg_entry["file"]
     cfg_dir = cfg_file.parent
     per_layer = [
         (m["name"], m["unit"],
-         load_module(BENCH / "metrics" / f"{m['name']}.py",
+         load_module(bench / "metrics" / f"{m['name']}.py",
                      _modname("metric", m["name"])))
         for m in benchmark["per_layer"]
         if name in m.get("workloads", [name])]
@@ -97,8 +117,8 @@ def load_cell(name: str, benchmark: dict | None = None) -> Cell:
         wl, json.loads(cfg_file.read_text()),
         load_module(cfg_dir / "model.py", _modname("model", wl["config"])),
         load_module(cfg_dir / "reference.py", _modname("ref", wl["config"])),
-        json.loads((BENCH / "traffic" / f"{wl['traffic']}.json").read_text()),
-        json.loads((BENCH / "limits" / f"{name}.json").read_text()),
+        json.loads((bench / "traffic" / f"{wl['traffic']}.json").read_text()),
+        json.loads((bench / "limits" / f"{name}.json").read_text()),
         per_layer)
 
 
@@ -119,15 +139,18 @@ def check_devices(chips: int):
 
 
 def enable_compile_cache() -> str:
-    """JAX's persistent compilation cache where the program keeps it
-    (``repro.launch.compile_cache``: ``JAX_COMPILATION_CACHE_DIR`` where it
-    is set, else ``.jax_cache`` in the checkout), keeping every program, so
-    that a run after the first compiles nothing."""
+    """JAX's persistent compilation cache in ``CACHE_DIR``, inside this
+    checkout, keeping every program, so that a run after the first compiles
+    nothing.
+
+    Never a directory that another checkout shares, so it overrides
+    ``JAX_COMPILATION_CACHE_DIR``: JAX's cache key leaves out op metadata,
+    so a shared cache can hand this checkout another's executable with the
+    other's scope names, which the per-layer metrics read."""
     import jax
 
-    from repro.launch.compile_cache import enable_compile_cache as enable
-
-    path = enable()
+    path = str(CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return path
@@ -177,12 +200,18 @@ def build_engine(cell: Cell):
 
 def rounds_flops(cell: Cell) -> float:
     """Model FLOPs of one global round: forward + backward (3x the forward)
-    of every sample of every active client's local steps."""
+    of every sample of every active client's local steps. A sample is a
+    row; for a causal LM, one sequence of the traffic's ``seq_len``."""
     G, K = cell.config["levels"]
     sched = cell.traffic["spec"]["schedule"]
     samples = G * K * sched["group_rounds"] * sched["local_steps"] \
-        * cell.traffic["batch"]
-    return 3.0 * cell.model.forward_flops(cell.config) * samples
+        * cell.microbatches * cell.traffic["batch"]
+    if cell.causal_lm:
+        per_sample = cell.model.forward_flops(
+            cell.config, seq_len=cell.traffic["seq_len"])
+    else:
+        per_sample = cell.model.forward_flops(cell.config)
+    return 3.0 * per_sample * samples
 
 
 class TracedRun:
@@ -228,10 +257,16 @@ def set_up(cell: Cell, seed: int, phases: dict | None = None):
         feed.jax_key(seed, "weights"))
     phases["engine_weights"] = time.perf_counter() - t
     t = time.perf_counter()
-    data = engine.pack_arrays(
-        {"x": fed.x, "y": fed.y}, fed.indices, batch_size=traffic["batch"],
-        shards=traffic["shards"], rng=feed.pack_rng(seed),
-        key=feed.jax_key(seed, "select"))
+    if cell.causal_lm:
+        data = engine.pack_tokens(
+            fed.streams, batch_size=traffic["batch"],
+            seq_len=traffic["seq_len"], shards=traffic["shards"],
+            rng=feed.pack_rng(seed), key=feed.jax_key(seed, "select"))
+    else:
+        data = engine.pack_arrays(
+            {"x": fed.x, "y": fed.y}, fed.indices,
+            batch_size=traffic["batch"], shards=traffic["shards"],
+            rng=feed.pack_rng(seed), key=feed.jax_key(seed, "select"))
     state = engine.init(params)
     x0 = jax.device_get(params)
     phases["pack_init"] = time.perf_counter() - t
@@ -301,18 +336,23 @@ def reference_readout(cell: Cell, seed: int, fed, x0, *, dtype=None,
     cfg, traffic = cell.config, cell.traffic
     G, K = cfg["levels"]
     sched = traffic["spec"]["schedule"]
-    E, H = sched["group_rounds"], sched["local_steps"]
+    E, H, A = sched["group_rounds"], sched["local_steps"], cell.microbatches
     rpc = traffic["rounds_per_call"]
     rounds = traffic["checked_calls"] * rpc
-    rows = feed.shard_rows(fed.indices, traffic["shards"], H,
-                           traffic["batch"], feed.pack_rng(seed))
+    arrays, rows = feed.packed_slots(fed, traffic, H * A, feed.pack_rng(seed))
     sids = feed.round_shards(feed.jax_key(seed, "select"), rounds, E, G, K,
                              traffic["shards"])
-    batches = [feed.round_batches(fed, rows, sids[r]) for r in range(rounds)]
+    batches = [feed.round_batches(arrays, rows, sids[r], A)
+               for r in range(rounds)]
+    if cell.causal_lm:
+        loss = functools.partial(cell.ref.loss, cfg)
+    else:
+        loss = functools.partial(reference.classification_loss,
+                                 functools.partial(cell.ref.forward, cfg))
     losses, models, corrections = reference.run_rounds(
-        functools.partial(cell.ref.forward, cfg), x0, batches,
-        levels=(G, K), lr=traffic["spec"]["lr"], group_rounds=E,
-        local_steps=H, dtype=dtype or cfg["dtype"],
+        loss, x0, batches, levels=(G, K), lr=traffic["spec"]["lr"],
+        group_rounds=E, local_steps=H, per_client=cell.causal_lm,
+        dtype=dtype or cfg["dtype"],
         precision=precision or cfg["matmul_precision"],
         batch_fraction=batch_fraction, drop_y=drop_y)
     return Readout(losses, models[rpc - 1], models[-1],
@@ -320,8 +360,10 @@ def reference_readout(cell: Cell, seed: int, fed, x0, *, dtype=None,
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
-             t0: float = _T0, require_tpu: bool = True) -> tuple[dict, list]:
-    """One run of ``cell``: returns ``(result, check_lines)``."""
+             t0: float = _T0, require_tpu: bool = True,
+             trace_dir: Path = TRACE_DIR) -> tuple[dict, list]:
+    """One run of ``cell``: returns ``(result, check_lines)``. A traced run
+    writes its trace under ``trace_dir`` and deletes it once read."""
     import jax
     import numpy as np
 
@@ -349,8 +391,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     # ---- the measured window -------------------------------------------
     if trace:
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
-        jax.profiler.start_trace(str(TRACE_DIR))
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        jax.profiler.start_trace(str(trace_dir))
     rounds = failed = 0
     counter.on = True
     start = time.perf_counter()
@@ -371,8 +413,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     reduced = None
     if trace:
         jax.profiler.stop_trace()
-        reduced = tr.reduce_dir(TRACE_DIR, chips)
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        reduced = tr.reduce_dir(trace_dir, chips)
+        shutil.rmtree(trace_dir, ignore_errors=True)
     stats = dev.memory_stats() or {}
     peak_bytes = device_peak_bytes(stats)
     del state, data, hz, engine

@@ -7,16 +7,12 @@ The program names the layers of an MTGC round with ``jax.named_scope``
 (``repro.launch.hlo_analysis.BUCKETS``: ``client_step``, ``local_update``,
 ``state_repack``, ``group_agg``, ``global_agg``) and writes host spans on
 the profiler's clock (``repro.fit``, ``repro.dispatch``, ``repro.fetch``).
-``jax.profiler.ProfileData`` gives each device op's time but not its
-name-stack path, which the op's metadata holds as ``tf_op``
-(``jit(run_chunk)/while/body/.../client_step/vmap(vmap(jvp()))/...``);
-``xspace.py`` reads that from the same file.
-
-An op belongs to the innermost declared scope on its path. The window,
-the ops counted and their times are ``trace.py``'s, so the scopes and the
-remainder add up to its op seconds. The result, per round of the window
-(by default one a ``bench.fit`` span, as the benchmark's cells run ``fit``
-one round at a time), is one JSON object:
+The window, the ops counted, their times and their ``tf_op`` paths are
+``trace.py``'s. Here an op belongs to the innermost declared scope on its
+path, so the scopes and the remainder add up to its op seconds. The
+result, per round of the window (by default one a ``bench.fit`` span, as
+the benchmark's cells run ``fit`` one round at a time), is one JSON
+object:
 
 - ``scope_ms``: device ms of each scope that some op falls under;
 - ``unscoped_ms`` and ``unscoped_top``: device ms under no scope, and its
@@ -27,8 +23,9 @@ one round at a time), is one JSON object:
   innermost host span, the benchmark's or the program's, open at its
   middle.
 
-The benchmark's own reader (``trace.py``) and its metrics do not read
-these names yet.
+The benchmark's per-layer metrics read the same names through
+``trace.Reduced.scope_seconds`` (an op counts for every scope on its path)
+and ``span_self_s``; this tool is for looking at a whole window.
 """
 from __future__ import annotations
 
@@ -42,7 +39,6 @@ from pathlib import Path
 
 from bench import trace as tr
 
-HOST_PREFIXES = ("bench.", "repro.")    # the benchmark's spans, the program's
 FIT_SPAN = "repro.fit"
 FETCH_SPAN = "repro.fetch"
 
@@ -74,16 +70,15 @@ def declared_scopes() -> tuple[str, ...]:
 class Split:
     """One traced window split by scope and by host span."""
 
-    reduced: tr.Reduced          # trace.py's window and ops; spans of both kinds
-    paths: dict[int, list[str]]  # chip -> the tf_op of each op of reduced.ops
+    reduced: tr.Reduced   # trace.py's window and ops; spans of both kinds
 
     def path_seconds(self) -> dict[str, float]:
         """Device seconds of the ops by ``tf_op`` path, averaged over the
         chips."""
         tot = collections.Counter()
-        for chip, ops in self.reduced.ops.items():
-            for op, path in zip(ops, self.paths[chip]):
-                tot[path] += (op.end_ns - op.start_ns) * 1e-9
+        for ops in self.reduced.ops.values():
+            for op in ops:
+                tot[op.path] += (op.end_ns - op.start_ns) * 1e-9
         return {k: v / max(len(self.reduced.ops), 1) for k, v in tot.items()}
 
     def scope_seconds(self, names) -> dict[str | None, float]:
@@ -97,62 +92,15 @@ class Split:
     def driver_host_s(self) -> float | None:
         """Host seconds in the window's ``repro.fit`` spans outside their
         ``repro.fetch`` spans; None where the window holds no such span."""
-        lo, hi = self.reduced.window
-        spans = self.reduced.spans
-        fits = [(s, e) for n, s, e in spans
-                if n == FIT_SPAN and lo <= s and e <= hi]
-        if not fits:
-            return None
-        fetches = [(s, e) for n, s, e in spans if n == FETCH_SPAN]
-        return 1e-9 * sum((e - s) - tr.busy_ns(fetches, s, e) for s, e in fits)
-
-
-def op_paths(path: str | Path) -> dict[str, list[tuple[str, str]]]:
-    """``(instruction text, tf_op)`` of every ``XLA Ops`` event of each
-    device plane, in the file's order (the order ``ProfileData`` gives)."""
-    from bench import xspace
-
-    out = {}
-    for plane in xspace.read_planes(path, tr.DEVICE_PLANE.match, [tr.OPS_LINE]):
-        out[plane.name] = [
-            (plane.metadata[ev.metadata_id][0],
-             str(plane.metadata[ev.metadata_id][1].get("tf_op", "")))
-            for line in plane.lines for ev in line.events]
-    return out
+        return self.reduced.span_self_s(FIT_SPAN, FETCH_SPAN)
 
 
 def split_file(path: str | Path, chips: int) -> Split:
     """Read one ``.xplane.pb`` (or ``.xplane.pb.gz``): ``trace.py``'s
-    window and ops, each op's ``tf_op``, and the host spans of both kinds."""
-    named = op_paths(path)
-    pd = tr.load_profile(path)
-    spans, ops = [], collections.defaultdict(list)
-    for plane in pd.planes:
-        m = tr.DEVICE_PLANE.match(plane.name)
-        for line in plane.lines:
-            if m and line.name == tr.OPS_LINE:
-                events = list(line.events)
-                if [n for n, _ in named[plane.name]] != [e.name for e in events]:
-                    raise ValueError(f"{path}: {plane.name}'s ops read "
-                                     "differently by the two readers")
-                for ev, (_, tf_op) in zip(events, named[plane.name]):
-                    if tr.opcode(ev.name) not in tr.CONTAINERS:
-                        ops[int(m.group(1))].append(
-                            (tr.Op(ev.name, ev.start_ns,
-                                   ev.start_ns + ev.duration_ns), tf_op))
-            elif plane.name == "/host:CPU":
-                spans += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
-                          for ev in line.events
-                          if ev.name.startswith(HOST_PREFIXES)]
-    windows = [(s, e) for n, s, e in spans if n == tr.WINDOW_SPAN]
-    if len(windows) != 1:
-        raise ValueError(f"{path}: {len(windows)} {tr.WINDOW_SPAN!r} spans")
-    lo, hi = windows[0]
-    kept = {c: [(o, p) for o, p in ops.get(c, [])
-                if o.end_ns > lo and o.start_ns < hi] for c in range(chips)}
-    return Split(tr.Reduced((lo, hi), {c: [o for o, _ in v]
-                                       for c, v in kept.items()}, spans),
-                 {c: [p for _, p in v] for c, v in kept.items()})
+    reduction, with the host spans of both kinds in ``spans`` so that an
+    idle gap is named by the innermost span of either."""
+    red = tr.reduce_file(path, chips)
+    return Split(dataclasses.replace(red, spans=red.spans + red.program_spans))
 
 
 def summary(split: Split, rounds: int | None = None, top: int = 5) -> dict:

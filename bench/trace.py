@@ -13,6 +13,13 @@ the instructions they run, so they are left out here: busy time is the
 union of the other instructions' intervals, and an instruction's time is
 its own. The line ``Async XLA Ops`` (DMAs in flight beside the compute) is
 not read.
+
+``ProfileData`` gives each op's time but not its name-stack path, which the
+op's metadata holds as ``tf_op`` (``jit(run_chunk)/while/body/client_step/
+vmap(vmap(jvp()))/mul:``); ``xspace.py`` reads that from the same file, so
+a per-layer metric can take the device time under any ``jax.named_scope``
+of the program (``Reduced.scope_seconds``). The program's own host spans
+(``repro.*``) are kept too, apart from the run's.
 """
 from __future__ import annotations
 
@@ -24,6 +31,8 @@ from pathlib import Path
 WINDOW_SPAN = "bench.window"
 FIT_SPAN = "bench.fit"
 SYNC_SPAN = "bench.sync"
+RUN_PREFIX = "bench."        # the run's own host spans
+PROGRAM_PREFIX = "repro."    # the program's host spans
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
@@ -60,11 +69,26 @@ def instruction(text: str) -> str:
     return re.sub(r"\.\d+$", "", name)
 
 
+def on_path(tf_op: str, name: str) -> bool:
+    """Whether scope ``name`` lies anywhere on the name-stack path ``tf_op``.
+
+    A name matches as a whole word inside a path component: JAX writes a
+    scope entered under a transform as ``vmap(vmap(client_step))`` or
+    ``transpose(jvp(client_step))``; ``my.client_step`` and
+    ``client_steps`` are other names. Of two paths joined by ``;`` the
+    first is read.
+    """
+    path = tf_op.split(";", 1)[0]
+    return re.search(r"(?<![\w.\-])" + re.escape(name) + r"(?![\w.\-])",
+                     path) is not None
+
+
 @dataclasses.dataclass
 class Op:
     name: str        # instruction text
     start_ns: float
     end_ns: float
+    path: str = ""   # the op's name-stack path (``tf_op``)
 
 
 @dataclasses.dataclass
@@ -74,6 +98,8 @@ class Reduced:
     window: tuple[float, float]
     ops: dict[int, list[Op]]                # chip -> its ops in the window
     spans: list[tuple[str, float, float]]   # the run's own host spans
+    program_spans: list[tuple[str, float, float]] = dataclasses.field(
+        default_factory=list)               # the program's host spans
 
     @property
     def window_s(self) -> float:
@@ -95,6 +121,32 @@ class Reduced:
             for o in ops:
                 tot[o.name] += (o.end_ns - o.start_ns) * 1e-9
         return {k: v / max(len(self.ops), 1) for k, v in tot.items()}
+
+    def scope_seconds(self, name: str) -> float | None:
+        """Device seconds of the ops that have scope ``name`` anywhere on
+        their path, averaged over the chips; None where no op has it.
+
+        Anywhere, not innermost: a scope that a later change nests inside
+        this one leaves its reading as it was."""
+        tot, found = 0.0, False
+        for ops in self.ops.values():
+            for o in ops:
+                if on_path(o.path, name):
+                    tot += (o.end_ns - o.start_ns) * 1e-9
+                    found = True
+        return tot / max(len(self.ops), 1) if found else None
+
+    def span_self_s(self, outer: str, inner: str) -> float | None:
+        """Host seconds of the window's ``outer`` program spans that no
+        ``inner`` program span covers; None where the window holds no
+        ``outer`` span."""
+        lo, hi = self.window
+        spans = self.program_spans
+        outs = [(s, e) for n, s, e in spans if n == outer and lo <= s and e <= hi]
+        if not outs:
+            return None
+        ins = [(s, e) for n, s, e in spans if n == inner]
+        return 1e-9 * sum((e - s) - busy_ns(ins, s, e) for s, e in outs)
 
 
 def merge(intervals) -> list[tuple[float, float]]:
@@ -140,32 +192,53 @@ def load_profile(path: str | Path):
     return ProfileData.from_file(str(path))
 
 
+def op_paths(path: str | Path) -> dict[str, list[tuple[str, str]]]:
+    """``(instruction text, tf_op)`` of every ``XLA Ops`` event of each
+    device plane, in the file's order (the order ``ProfileData`` gives)."""
+    from bench import xspace
+
+    out = {}
+    for plane in xspace.read_planes(path, DEVICE_PLANE.match, [OPS_LINE]):
+        out[plane.name] = [
+            (plane.metadata[ev.metadata_id][0],
+             str(plane.metadata[ev.metadata_id][1].get("tf_op", "")))
+            for line in plane.lines for ev in line.events]
+    return out
+
+
 def reduce_file(path: str | Path, chips: int) -> Reduced:
     """Read one ``.xplane.pb`` (or ``.xplane.pb.gz``) into a
     :class:`Reduced` window."""
+    named = op_paths(path)
     pd = load_profile(path)
-    spans, ops = [], collections.defaultdict(list)
+    spans, program, ops = [], [], collections.defaultdict(list)
     for plane in pd.planes:
         m = DEVICE_PLANE.match(plane.name)
         for line in plane.lines:
             if m and line.name == OPS_LINE:
-                for ev in line.events:
+                events = list(line.events)
+                if [n for n, _ in named[plane.name]] != [e.name for e in events]:
+                    raise ValueError(f"{path}: {plane.name}'s ops read "
+                                     "differently by the two readers")
+                for ev, (_, tf_op) in zip(events, named[plane.name]):
                     if opcode(ev.name) not in CONTAINERS:
                         ops[int(m.group(1))].append(
                             Op(ev.name, ev.start_ns,
-                               ev.start_ns + ev.duration_ns))
+                               ev.start_ns + ev.duration_ns, tf_op))
             elif plane.name == "/host:CPU":
                 for ev in line.events:
-                    if ev.name.startswith("bench."):
-                        spans.append((ev.name, ev.start_ns,
-                                      ev.start_ns + ev.duration_ns))
+                    span = (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                    if ev.name.startswith(RUN_PREFIX):
+                        spans.append(span)
+                    elif ev.name.startswith(PROGRAM_PREFIX):
+                        program.append(span)
     windows = [(s, e) for n, s, e in spans if n == WINDOW_SPAN]
     if len(windows) != 1:
         raise ValueError(f"{path}: {len(windows)} {WINDOW_SPAN!r} spans")
     lo, hi = windows[0]
     chip_ops = {c: [o for o in ops.get(c, []) if o.end_ns > lo and o.start_ns < hi]
                 for c in range(chips)}
-    return Reduced((lo, hi), chip_ops, spans)
+    return Reduced((lo, hi), chip_ops, spans, program)
 
 
 def reduce_dir(trace_dir: str | Path, chips: int) -> Reduced:

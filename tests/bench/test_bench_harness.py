@@ -16,10 +16,9 @@ import sys
 import jax
 import pytest
 
-import repro.api
-import repro.core.engine as engine_mod
 from bench import check, run
-from bench_cells import WORKLOADS, allow_cpu_peaks, tiny_cell
+from bench_cells import (WORKLOADS, allow_cpu_peaks, control_cell,
+                         plant_fault, tiny_cell)
 
 
 def test_refuses_without_a_tpu_and_names_the_platform(capsys):
@@ -49,11 +48,11 @@ def test_refuses_in_a_checkout_without_the_program(tmp_path):
 
 
 @pytest.mark.parametrize("workload", WORKLOADS)
-def test_sound_run_is_correct(workload, monkeypatch):
+def test_sound_run_is_correct(workload, monkeypatch, tmp_path):
     allow_cpu_peaks(monkeypatch)
     cell = tiny_cell(workload)
     result, lines = run.run_cell(cell, 2**31 + 9, 0.3, trace=True,
-                                 require_tpu=False)
+                                 require_tpu=False, trace_dir=tmp_path)
     assert result["correct"], lines
     assert list(result)[-1] == "checks"
     assert set(result["checks"]) == set(cell.limits) <= set(check.NUMBERS)
@@ -61,60 +60,23 @@ def test_sound_run_is_correct(workload, monkeypatch):
     json.dumps(result)
 
 
-def _frozen_build(orig):
-    """``repro.api.build`` whose round returns the state it was given."""
-    def build(spec, loss_fn):
-        engine = orig(spec, loss_fn)
-        round_fn = engine.round_fn
-
-        def frozen(state, batches):
-            _, metrics = round_fn(state, batches)
-            return state, metrics
-
-        engine.round_fn = frozen
-        return engine
-    return build
-
-
-def _half_batch(orig):
-    """The clients' gradients on the first half of each batch, the mean
-    taken over that half."""
-    def client_grads(loss_fn, params, batch):
-        half = jax.tree.map(lambda b: b[:, :, : b.shape[2] // 2], batch)
-        return orig(loss_fn, params, half)
-    return client_grads
-
-
 @pytest.mark.parametrize("fault", ["state_unchanged", "half_batch"])
 @pytest.mark.parametrize("workload", WORKLOADS)
 def test_broken_timed_path_is_not_correct(workload, fault, monkeypatch):
     allow_cpu_peaks(monkeypatch)
-    if fault == "state_unchanged":
-        monkeypatch.setattr(repro.api, "build", _frozen_build(repro.api.build))
-    else:
-        monkeypatch.setattr(engine_mod, "_client_grads",
-                            _half_batch(engine_mod._client_grads))
-    result, lines = run.run_cell(tiny_cell(workload), 1, 0.2,
-                                 trace=False, require_tpu=False)
+    cell = tiny_cell(workload)
+    plant_fault(monkeypatch, fault, cell)
+    result, lines = run.run_cell(cell, 1, 0.2, trace=False, require_tpu=False)
     assert not result["correct"], lines
-
-
-# Sizes at which a CPU test holds the control: the CNN at its published
-# widths, the ResNet cut.
-CONTROL_SIZES = {
-    "cnn-cifar10.full": {"image_shape": [32, 32, 3], "levels": [2, 2]},
-    "resnet18gn-cifar100.full": {"image_shape": [16, 16, 3], "levels": [2, 2]},
-}
 
 
 @pytest.mark.parametrize("workload", WORKLOADS)
 def test_bfloat16_control_is_not_correct(workload):
     """The reference computed in bfloat16, the precision below the
     configuration's float32, in the program's place, held to the cell's
-    limits."""
-    cell = tiny_cell(workload)
-    cell.config.update(CONTROL_SIZES[workload])
-    cell.traffic.update(batch=8, samples_per_client=100)
+    limits, at the size its ``tiny.json`` gives the control (the CNN at its
+    published widths, the ResNet cut)."""
+    cell = control_cell(workload)
     seed = 1
     fed, _, _, _, x0 = run.set_up(cell, seed)
     ref = run.reference_readout(cell, seed, fed, x0)
@@ -146,3 +108,20 @@ def test_device_peak_counts_the_programs_reserved_scratch():
                                   "peak_bytes_reserved": 4}) == 7
     assert run.device_peak_bytes({"peak_bytes_in_use": 3}) == 3
     assert run.device_peak_bytes({}) is None
+
+
+def test_compile_cache_stays_in_the_checkout(monkeypatch, tmp_path):
+    """A cache directory set for the machine is not used: another checkout
+    could share it and hand this one its executables with its own scope
+    names."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    before = {n: getattr(jax.config, n) for n in names}
+    try:
+        assert run.enable_compile_cache() == str(run.ROOT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(run.CACHE_DIR)
+    finally:
+        for n, v in before.items():
+            jax.config.update(n, v)

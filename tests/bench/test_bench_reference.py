@@ -6,27 +6,17 @@ the algorithm the engine runs would read far above these bounds.
 """
 from __future__ import annotations
 
-import jax
 import numpy as np
 import pytest
 
 from bench import check, feed, run
-from bench_cells import WORKLOADS, tiny_cell
+from bench_cells import WORKLOADS, program_and_reference_loss, tiny_cell
 
 
 @pytest.mark.parametrize("workload", WORKLOADS)
 def test_reference_forward_matches_program_model(workload):
-    cell = tiny_cell(workload)
-    cfg = cell.config
-    params = cell.ref.init_weights(cfg, jax.random.PRNGKey(3))
-    loss_fn, _ = cell.model.program_loss(cfg)
-    x = jax.random.normal(jax.random.PRNGKey(4),
-                          (6, int(np.prod(cfg["image_shape"]))))
-    y = jax.random.randint(jax.random.PRNGKey(5), (6,), 0, cfg["num_classes"])
-    logp = jax.nn.log_softmax(cell.ref.forward(cfg, params, x), axis=-1)
-    want = -np.mean(np.asarray(logp)[np.arange(6), np.asarray(y)])
-    np.testing.assert_allclose(loss_fn(params, {"x": x, "y": y}), want,
-                               rtol=1e-6)
+    got, want = program_and_reference_loss(tiny_cell(workload))
+    np.testing.assert_allclose(got, want, rtol=1e-6)
 
 
 @pytest.mark.parametrize("workload", WORKLOADS)

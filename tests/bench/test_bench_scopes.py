@@ -159,3 +159,62 @@ def test_summary_line(scoped):
     assert len(out["unscoped_top"]) <= 5
     assert out["driver_host_ms"] > 0
     json.dumps(out)
+
+
+# The benchmark's readers of the program's names, and the scope each reads.
+SCOPE_METRICS = {"step.fwd_bwd_ms": "client_step", "update.ms": "local_update",
+                 "pack.repack_ms": "state_repack", "agg.group_ms": "group_agg",
+                 "agg.global_ms": "global_agg"}
+
+
+@pytest.mark.parametrize("metric,scope", SCOPE_METRICS.items())
+def test_scope_reader_is_the_splits_number(scoped, metric, scope):
+    run_ = run.TracedRun(tr.reduce_file(SCOPED, 1), 2, 1.0, {}, 1)
+    want = scopes.summary(scoped)["scope_ms"][scope]
+    assert _metric(metric).read(run_) == pytest.approx(want, rel=1e-9)
+
+
+def test_driver_reader_is_the_splits_number(scoped):
+    run_ = run.TracedRun(tr.reduce_file(SCOPED, 1), 2, 1.0, {}, 1)
+    want = scopes.summary(scoped)["driver_host_ms"]
+    assert _metric("driver.host_ms").read(run_) == pytest.approx(want,
+                                                                 rel=1e-9)
+
+
+def test_engine_scopes_are_disjoint_on_the_trace(scoped):
+    """No op lies under two of the round's scopes, so an op counted for
+    every scope on its path and one counted for its innermost agree."""
+    for op in scoped.reduced.ops[0]:
+        assert sum(tr.on_path(op.path, s) for s in ENGINE_SCOPES) <= 1, op.path
+    inner = scoped.scope_seconds(ENGINE_SCOPES)
+    for s in ENGINE_SCOPES:
+        assert scoped.reduced.scope_seconds(s) == pytest.approx(inner[s])
+
+
+def test_readers_of_names_read_nothing_without_them():
+    run_ = run.TracedRun(tr.reduce_file(SMALL, 1), 2, 1.0, {}, 1)
+    for metric in [*SCOPE_METRICS, "driver.host_ms"]:
+        assert _metric(metric).read(run_) is None, metric
+
+
+def test_reduced_keeps_paths_and_program_spans_apart():
+    red = tr.reduce_file(SCOPED, 1)
+    assert all(n.startswith(tr.RUN_PREFIX) for n, _, _ in red.spans)
+    assert {n for n, _, _ in red.program_spans} >= {
+        "repro.fit", "repro.dispatch", "repro.fetch"}
+    assert sum(bool(o.path) for o in red.ops[0]) > 0.9 * len(red.ops[0])
+
+
+@pytest.mark.parametrize("tf_op,name,want", [
+    ("jit(f)/local_update/state_repack/slice:", "local_update", True),
+    ("jit(f)/local_update/state_repack/slice:", "state_repack", True),
+    ("jit(f)/transpose(jvp(client_step))/dot_general:", "client_step", True),
+    ("jit(f)/client_step/clients_packed/conv:", "clients_packed", True),
+    ("jit(f)/client_steps/add:", "client_step", False),
+    ("jit(f)/my.client_step/add:", "client_step", False),
+    ("jit(f)/group_agg/sub:;jit(f)/global_agg/sub:", "global_agg", False),
+    ("", "client_step", False),
+], ids=["outer", "inner", "transposed", "nested_name", "longer_word",
+        "dotted_word", "second_path", "empty"])
+def test_a_scope_anywhere_on_the_path(tf_op, name, want):
+    assert tr.on_path(tf_op, name) is want
