@@ -17,10 +17,20 @@ microbatches of B rows: the mean of the A microbatch gradients, which is
 the gradient of the mean over the A*B rows. Classification merges the
 microbatches into one batch and runs the clients group by group
 (``lax.map`` over groups, ``vmap`` over a group's clients), so the
-reference needs about a G-th of the activations the program holds. With
-``per_client`` (causal LMs) it maps over the clients one at a time and over
-each client's microbatches, so it holds one microbatch of one client's
-activations.
+reference needs about a G-th of the activations the program holds.
+
+With ``per_client`` (causal LMs) the clients run one at a time, each over
+its microbatches, and the state of the round stays in host memory: every
+replica's x_i and z_i and every group's y_j, in the run's precision. A local
+step puts one client's x_i, z_i and y_j on the device, runs one jitted call
+that takes x_i's buffer for the new x_i, and brings x_i back; moving float32
+or bfloat16 between host and device is exact. The group and global
+aggregations run leaf by leaf on the device, through the same jitted means
+and corrections as the whole-state path. So the device holds one client's
+x, z, y, gradient and accumulator and one microbatch's activations, whatever
+G x K; an aggregation holds one leaf of every replica. The whole-state path
+with ``per_client`` (``host_state=False``) is the one the host path is
+tested against.
 
 ``dtype`` is the precision everything is held and computed in; float32
 runs under ``default_matmul_precision(precision)``: ``"default"``, the precision a configuration states (one
@@ -33,6 +43,7 @@ leaves y out of the local step (y is still updated).
 from __future__ import annotations
 
 import contextlib
+import functools
 from typing import Callable
 
 import jax
@@ -69,6 +80,7 @@ def classification_loss(forward: Callable, p, batch: dict):
 def run_rounds(loss: Callable, params0: dict, batches: list[dict], *,
                levels: tuple[int, int], lr: float, group_rounds: int,
                local_steps: int, per_client: bool = False,
+               host_state: bool = True,
                dtype=jnp.float32, precision: str = "default",
                batch_fraction: float = 1.0, drop_y: bool = False
                ) -> tuple[np.ndarray, list[dict], list[dict]]:
@@ -76,11 +88,12 @@ def run_rounds(loss: Callable, params0: dict, batches: list[dict], *,
 
     ``batches[r]`` holds round r's named host arrays, each ``[E, H, G, K,
     A, B, ...]`` (A microbatches of B rows a local step). ``loss(params,
-    batch)`` is one client's loss on one batch of rows. Returns the mean
-    client loss of every local step, ``[R, E, H]`` float64, the global
-    model after every round as host float64 trees, and after every round
-    the norm of each leaf of the corrections z (of the round's last group
-    round) and y.
+    batch)`` is one client's loss on one batch of rows. With
+    ``per_client``, ``host_state`` keeps the round's state in host memory
+    and one client's on the device. Returns the mean client
+    loss of every local step, ``[R, E, H]`` float64, the global model after
+    every round as host float64 trees, and after every round the norm of
+    each leaf of the corrections z (of the round's last group round) and y.
     """
     G, K = levels
     E, H = group_rounds, local_steps
@@ -117,12 +130,18 @@ def run_rounds(loss: Callable, params0: dict, batches: list[dict], *,
                            p, g, z, y)
         return new, lv
 
+    def cut(b, rows_axis):
+        """The first ``batch_fraction`` of every batch's rows."""
+        if batch_fraction < 1.0:
+            n = jax.tree.leaves(b)[0].shape[rows_axis]
+            keep = max(1, int(n * batch_fraction))
+            b = {k: jax.lax.slice_in_dim(a, 0, keep, axis=rows_axis)
+                 for k, a in b.items()}
+        return cast_in(b)
+
     @jax.jit
     def local_step(x, z, y, b):
-        if batch_fraction < 1.0:
-            keep = max(1, int(jax.tree.leaves(b)[0].shape[3] * batch_fraction))
-            b = {k: a[:, :, :, :keep] for k, a in b.items()}
-        b = cast_in(b)
+        b = cut(b, 3)
         if per_client:
             def group(args):
                 xg, zg, yg, bg = args
@@ -140,6 +159,12 @@ def run_rounds(loss: Callable, params0: dict, batches: list[dict], *,
 
         x, lv = jax.lax.map(group, (x, z, y, b))
         return x, jnp.mean(lv.astype(jnp.float32))
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def client_step(x, z, y, b):
+        """One local step of one client; ``b`` holds its ``[A, B, ...]``
+        microbatches, and ``x``'s buffers take the new x."""
+        return client(x, z, y, cut(b, 1))
 
     @jax.jit
     def group_aggregate(x, z):
@@ -159,11 +184,10 @@ def run_rounds(loss: Callable, params0: dict, batches: list[dict], *,
         x = jax.tree.map(lambda b: jnp.broadcast_to(b, (G, K) + b.shape), xbar)
         return x, y, xbar
 
-    with precision:
+    def device_rounds():
         p0 = cast(params0)
         x = jax.tree.map(lambda a: jnp.broadcast_to(a, (G, K) + a.shape), p0)
         y = jax.tree.map(lambda a: jnp.zeros((G,) + a.shape, dt), p0)
-        losses, models, corrections = [], [], []
         for rb in batches:
             z = jax.tree.map(jnp.zeros_like, x)
             for e in range(E):
@@ -178,8 +202,54 @@ def run_rounds(loss: Callable, params0: dict, batches: list[dict], *,
             models.append(jax.tree.map(
                 lambda a: np.asarray(a, np.float64), xbar))
             corrections.append({"z": _norms(z), "y": _norms(y)})
-        losses = np.asarray(losses, np.float64)
+
+    def host_rounds():
+        # Leaves of the state, [G, K, ...] (x, z) and [G, ...] (y), on the
+        # host in the run's precision.
+        p0, tree = jax.tree.flatten(params0)
+        p0 = [np.array(jnp.asarray(a, dt)) for a in p0]
+        x = [np.broadcast_to(a, (G, K) + a.shape).copy() for a in p0]
+        y = [np.zeros((G,) + a.shape, dt) for a in p0]
+        at = lambda leaves, *i: jax.device_put(
+            jax.tree.unflatten(tree, [a[i] for a in leaves]))
+        for rb in batches:
+            z = [np.zeros_like(a) for a in x]
+            for e in range(E):
+                for h in range(H):
+                    lv = np.empty((G, K), np.float32)
+                    for g in range(G):
+                        yg = at(y, g)
+                        for k in range(K):
+                            new, lv[g, k] = client_step(
+                                at(x, g, k), at(z, g, k), yg,
+                                {n: a[e, h, g, k] for n, a in rb.items()})
+                            for a, v in zip(x, _to_host(new)):
+                                a[g, k] = v
+                            # Dropped before the next step or aggregation:
+                            # the device holds one client's state at a time.
+                            del new
+                        del yg
+                    losses.append(float(jnp.mean(lv)))
+                for i in range(len(x)):
+                    x[i], z[i] = _to_host(group_aggregate(x[i], z[i]))
+            xbar = []
+            for i in range(len(x)):
+                x[i], y[i], xb = _to_host(global_aggregate(x[i], y[i]))
+                xbar.append(np.asarray(xb, np.float64))
+            models.append(jax.tree.unflatten(tree, xbar))
+            corrections.append({"z": _norms(jax.tree.unflatten(tree, z)),
+                                "y": _norms(jax.tree.unflatten(tree, y))})
+
+    losses, models, corrections = [], [], []
+    with precision:
+        (host_rounds if per_client and host_state else device_rounds)()
+    losses = np.asarray(losses, np.float64)
     return losses.reshape(len(batches), E, H), models, corrections
+
+
+def _to_host(tree) -> list[np.ndarray]:
+    """The leaves of a device tree as writable host arrays."""
+    return [np.array(a) for a in jax.tree.leaves(tree)]
 
 
 def _norms(tree) -> dict[str, float]:

@@ -19,9 +19,9 @@ names a configuration and a traffic mix; the run
    ``--seconds`` have passed, then waits for the device. ``--trace 1``
    records a profiler trace of this window;
 5. reads the peak of device memory (buffers in use and the scratch the
-   compiled programs reserve), frees the program's state, trains the
-   checked rounds again with the plain reference and compares
-   (``check.py``).
+   compiled programs reserve) on the fullest of the cell's chips, frees the
+   program's state, trains the checked rounds again with the plain
+   reference and compares (``check.py``).
 
 The last line of standard output is one JSON object; the numbers compared,
 each beside its limit, are the last lines of standard error and the last
@@ -295,14 +295,21 @@ def drive_checked(cell: Cell, engine, data, state):
                                 corrections, correction_norms(state))
 
 
-def device_peak_bytes(stats: dict) -> int | None:
-    """The most device memory the run held: the peak of the buffers in use
-    plus the peak that the compiled programs reserved for their scratch
-    (on a TPU the temporaries of a program are reserved apart from the
-    buffers in use). None where the device reports neither (the CPU)."""
+def chip_peak_bytes(stats: dict) -> int | None:
+    """The most memory one device held: the peak of the buffers in use plus
+    the peak that the compiled programs reserved for their scratch (on a
+    TPU the temporaries of a program are reserved apart from the buffers in
+    use). None where the device reports neither (the CPU)."""
     if "peak_bytes_in_use" not in stats:
         return None
     return stats["peak_bytes_in_use"] + stats.get("peak_bytes_reserved", 0)
+
+
+def device_peak_bytes(stats: list[dict]) -> int | None:
+    """The most memory the run held on its fullest device, from each
+    device's ``memory_stats()``; None where no device reports it."""
+    peaks = [p for p in map(chip_peak_bytes, stats) if p is not None]
+    return max(peaks, default=None)
 
 
 def correction_norms(state) -> dict:
@@ -415,7 +422,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         jax.profiler.stop_trace()
         reduced = tr.reduce_dir(trace_dir, chips)
         shutil.rmtree(trace_dir, ignore_errors=True)
-    stats = dev.memory_stats() or {}
+    stats = [d.memory_stats() or {} for d in devices]
     peak_bytes = device_peak_bytes(stats)
     del state, data, hz, engine
     gc.collect()
@@ -430,7 +437,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     # ---- the result line -------------------------------------------------
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(devices), "memory_peak_bytes": peak_bytes}
+              "count": len(devices), "memory_peak_bytes": peak_bytes,
+              "memory_peak_bytes_per_chip": [chip_peak_bytes(c)
+                                             for c in stats]}
     if trace:
         run = TracedRun(reduced, rounds, rounds_flops(cell),
                         peaks_for(dev.device_kind), chips)
@@ -455,8 +464,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
              f"rounds in window {rounds}, compiles in window {counter.count}, "
              "worst leaves: " + ", ".join(
                  f"{k} {read[k + '_leaf']}" for k in check.LEAF_NUMBERS)]
-    lines.append("memory_stats: " + ", ".join(
-        f"{k} {v}" for k, v in sorted(stats.items())))
+    lines += [f"memory_stats {d.id}: " + ", ".join(
+        f"{k} {v}" for k, v in sorted(c.items()))
+        for d, c in zip(devices, stats)]
     lines.append("not compared: " + ", ".join(
         f"{n} {read[n]:.6g}" for n in check.NUMBERS if n not in checks))
     lines += [f"{n} {c['value']!r} limit {c['limit']!r}"

@@ -52,11 +52,12 @@ def control_cell(workload: str, root: Path = run.ROOT) -> run.Cell:
     return cell
 
 
-def lm_checkout(root: Path) -> Path:
+def lm_checkout(root: Path, four_chips: bool = False) -> Path:
     """A checkout at ``root``: this one's ``bench/`` and ``BENCHMARK.json``
     plus the ``lm-tiny`` cell, added as a new configuration arrives: new
     files under ``bench/configs``, ``bench/traffic``, ``bench/limits`` and
-    ``bench/metrics``, and new ``BENCHMARK.json`` entries."""
+    ``bench/metrics``, and new ``BENCHMARK.json`` entries. With
+    ``four_chips`` the cell asks for four chips (``four_chips.json``)."""
     shutil.copytree(run.BENCH, root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     for sub in ("configs", "traffic", "limits", "metrics"):
@@ -69,6 +70,8 @@ def lm_checkout(root: Path) -> Path:
                 shutil.copy(f, dest)
     benchmark = json.loads((run.ROOT / "BENCHMARK.json").read_text())
     added = json.loads((LM_TINY / "benchmark.json").read_text())
+    if four_chips:
+        added.update(json.loads((LM_TINY / "four_chips.json").read_text()))
     for key, entries in added.items():
         benchmark[key] += entries
     (root / "BENCHMARK.json").write_text(json.dumps(benchmark, indent=1))

@@ -104,10 +104,23 @@ def test_y_left_out_of_the_local_step_is_not_correct(workload):
 
 
 def test_device_peak_counts_the_programs_reserved_scratch():
-    assert run.device_peak_bytes({"peak_bytes_in_use": 3,
-                                  "peak_bytes_reserved": 4}) == 7
-    assert run.device_peak_bytes({"peak_bytes_in_use": 3}) == 3
-    assert run.device_peak_bytes({}) is None
+    assert run.device_peak_bytes([{"peak_bytes_in_use": 3,
+                                   "peak_bytes_reserved": 4}]) == 7
+    assert run.device_peak_bytes([{"peak_bytes_in_use": 3}]) == 3
+    assert run.device_peak_bytes([{}]) is None
+
+
+def test_device_peak_reads_the_fullest_chip():
+    """Four chips' ``memory_stats()``: the fullest counts, in use plus
+    reserved, whichever chip it is."""
+    stats = [{"peak_bytes_in_use": 9, "peak_bytes_reserved": 1},
+             {"peak_bytes_in_use": 6, "peak_bytes_reserved": 8},
+             {"peak_bytes_in_use": 12},
+             {"peak_bytes_in_use": 2, "peak_bytes_reserved": 2}]
+    assert run.device_peak_bytes(stats) == 14
+    assert [run.chip_peak_bytes(s) for s in stats] == [10, 14, 12, 4]
+    assert run.device_peak_bytes(stats[:1] + [{}] * 3) == 10
+    assert run.device_peak_bytes([{}] * 4) is None
 
 
 def test_compile_cache_stays_in_the_checkout(monkeypatch, tmp_path):

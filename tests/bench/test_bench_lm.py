@@ -10,12 +10,14 @@ nothing of the harness is edited.
 """
 from __future__ import annotations
 
+import functools
 import json
 
+import jax
 import numpy as np
 import pytest
 
-from bench import check, feed, run
+from bench import check, feed, reference, run
 from bench_cells import (allow_cpu_peaks, lm_cell, lm_checkout, plant_fault,
                          program_and_reference_loss)
 
@@ -92,6 +94,77 @@ def test_control_and_dropped_y_are_not_correct(root, kind):
         assert read["z"] == read["y"] == 0.0, read
     correct, checks = check.judge(read, cell.limits)
     assert not correct, checks
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_host_state_reference_reads_as_the_whole_state_path(root, backend,
+                                                            monkeypatch):
+    """The per-client reference that keeps the round's state on the host
+    reads as the whole-state device path it replaced: step losses, the
+    global models after every round and the z and y leaf norms.
+
+    Each client step is the same jitted client computation (alone, not a
+    ``lax.map`` body), each aggregation the same jitted means and
+    corrections (one leaf at a time), and host-device copies are exact, so
+    where XLA lowers the lone client step as it lowers the map's body, as
+    on the CPU, they agree bit for bit. A chip may lower the two apart and
+    differ in the last bits: 1e-5 relative. The bfloat16 control reads
+    gaps a hundred times wider."""
+    cell = lm_cell(root, *BACKENDS[backend])
+    seed = 2**31 + 19
+    fed, _, _, _, x0 = run.set_up(cell, seed)
+    host = run.reference_readout(cell, seed, fed, x0)
+    control = run.reference_readout(cell, seed, fed, x0, dtype="bfloat16")
+    monkeypatch.setattr(reference, "run_rounds", functools.partial(
+        reference.run_rounds, host_state=False))
+    whole = run.reference_readout(cell, seed, fed, x0)
+    np.testing.assert_array_equal(host.losses, whole.losses)
+    for part in ("first", "last"):
+        for a, b in zip(jax.tree.leaves(getattr(host, part)),
+                        jax.tree.leaves(getattr(whole, part))):
+            np.testing.assert_array_equal(a, b)
+    assert host.corrections == whole.corrections
+    assert host.corrections_last == whole.corrections_last
+    gap = np.abs(control.losses - whole.losses) / np.abs(whole.losses)
+    assert gap.max() > 100 * 1e-5, gap.max()
+
+
+def test_host_state_reference_holds_one_client_on_the_device(root,
+                                                            monkeypatch):
+    """Between client steps the device holds the same bytes at G x K = 4
+    and 16: the client's new x and its group's y, within one client's x, z,
+    y and gradient. Sampled each time a client's new x comes back to the
+    host; arrays alive before the call are not counted."""
+    cell = lm_cell(root)
+    cfg = cell.config
+    loss = functools.partial(cell.ref.loss, cfg)
+    params = jax.device_get(cell.ref.init_weights(cfg, jax.random.PRNGKey(0)))
+    n_bytes = sum(a.nbytes for a in jax.tree.leaves(params))
+    samples = []
+    to_host = reference._to_host
+
+    def sampled(tree):
+        if isinstance(tree, dict):            # a client's new x
+            samples[-1].append(sum(
+                a.nbytes for a in jax.live_arrays()
+                if id(a) not in before and not a.is_deleted()))
+        return to_host(tree)
+
+    monkeypatch.setattr(reference, "_to_host", sampled)
+    rng = np.random.default_rng(0)
+    for G, K in ((2, 2), (4, 4)):
+        toks = rng.integers(0, cfg["vocab_size"], (2, 2, G, K, 1, 2, 33),
+                            dtype=np.int32)
+        batch = {"tokens": toks[..., :-1], "targets": toks[..., 1:]}
+        alive = jax.live_arrays()
+        before = {id(a) for a in alive}
+        samples.append([])
+        reference.run_rounds(loss, params, [batch], levels=(G, K), lr=0.05,
+                             group_rounds=2, local_steps=2, per_client=True)
+        del alive
+        assert len(samples[-1]) == 2 * 2 * G * K
+    assert max(samples[0]) <= 4 * n_bytes + 4096, (samples, n_bytes)
+    assert set(samples[0]) == set(samples[1]), samples
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
