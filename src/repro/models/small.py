@@ -25,6 +25,7 @@ and the LSTM have no packed apply, and the engine vmaps them.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Callable, Tuple
 
@@ -204,20 +205,59 @@ def _groupnorm_packed(p, x, groups):
     without reshaping the activation: statistics reduce over H and W first,
     then over each group's channels on the small ``[B, C*c]`` array, and
     broadcast back. The variance is two-pass, as ``jnp.var``'s: the mean of
-    (x - mu)^2."""
+    (x - mu)^2. Differentiated by ``_gn_packed``'s analytic backward."""
     _, n = _clients(p["scale"], 1)
-    bsz, _, _, nc = x.shape
-    g = min(groups, nc // n)
+    g = min(groups, x.shape[-1] // n)
+    return _gn_packed(x, p["scale"].reshape(-1), p["bias"].reshape(-1), n * g)
 
-    def group_mean(t):            # [B, n*c] -> each channel its group's mean
-        t = t.reshape(bsz, n * g, -1)
-        t = jnp.broadcast_to(t.mean(axis=-1, keepdims=True), t.shape)
-        return t.reshape(bsz, nc)[:, None, None, :]
 
-    d = x - group_mean(x.mean(axis=(1, 2)))
-    var = group_mean(jax.lax.square(d).mean(axis=(1, 2)))
-    return (d * jax.lax.rsqrt(var + 1e-5) * p["scale"].reshape(-1)
-            + p["bias"].reshape(-1))
+def _group_mean(t, ng):
+    """``[B, n*c]`` -> each channel the mean over its group's channels."""
+    s = t.reshape(t.shape[0], ng, -1)
+    return jnp.broadcast_to(s.mean(axis=-1, keepdims=True), s.shape).reshape(
+        t.shape)
+
+
+def _gn_packed_fwd(x, scale, bias, ng):
+    """Output and residuals ``(x, mu, rstd, scale)``; ``mu`` and ``rstd``
+    are ``[B, n*c]``, each channel its group's."""
+    with jax.named_scope("groupnorm"):
+        mu = _group_mean(x.mean(axis=(1, 2)), ng)
+        d = x - mu[:, None, None, :]
+        var = _group_mean(jax.lax.square(d).mean(axis=(1, 2)), ng)
+        rstd = jax.lax.rsqrt(var + 1e-5)
+        y = d * rstd[:, None, None, :] * scale + bias
+    return y, (x, mu, rstd, scale)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gn_packed(x, scale, bias, ng):
+    return _gn_packed_fwd(x, scale, bias, ng)[0]
+
+
+def _gn_packed_bwd(ng, res, dy):
+    """Analytic backward. The per-image sums s1 = sum_HW dy, s2 = sum_HW
+    dy d and s3 = sum_HW d, with d = x - mu, read only dy and x; the scale
+    and bias gradients and dx's group terms follow from them on ``[B,
+    n*c]``, and one elementwise pass gives dx. It reduces dy d, never
+    dy x - mu s1, and keeps s3, the rounding mean of d that autodiff of the
+    two-pass form carries into dx: so it is as precise as that form."""
+    x, mu, rstd, scale = res
+    with jax.named_scope("groupnorm"):
+        hw = x.shape[1] * x.shape[2]
+        d = x - mu[:, None, None, :]
+        s1 = dy.sum(axis=(1, 2))
+        s2 = (dy * d).sum(axis=(1, 2))
+        s3 = d.sum(axis=(1, 2))
+        a = _group_mean(scale * s1, ng) / hw            # mean of dy scale
+        b = _group_mean(scale * rstd * s2, ng) / hw     # of dy scale xhat
+        c = rstd * (a - rstd * b * _group_mean(s3, ng) / hw)
+        dx = (rstd[:, None, None, :] * scale * dy - c[:, None, None, :]
+              - (rstd * rstd * b)[:, None, None, :] * d)
+        return dx, (rstd * s2).sum(axis=0), s1.sum(axis=0)
+
+
+_gn_packed.defvjp(_gn_packed_fwd, _gn_packed_bwd)
 
 
 def _gn_params(c):

@@ -26,6 +26,7 @@ import pytest
 
 import repro.core.engine as engine_mod
 from repro.api import ExperimentSpec, RoundSchedule, build
+from repro.models import small
 from repro.models.small import cnn, lstm, make_loss, mlp, resnet_gn
 
 B = 7                  # a batch size no width, kernel or class count shares
@@ -296,3 +297,136 @@ def test_packed_step_has_no_activation_relayouts(model, allowed, vmapped):
     # The same model through vmap's batching rules: a relayout around
     # every bias add and GroupNorm, forward and backward.
     assert len(_activation_transposes(_stripped(loss), init)) >= vmapped
+
+
+# ------------------------------------------------- GroupNorm's backward
+
+
+def _two_pass_groupnorm(p, x, groups):
+    """The packed GroupNorm in the two-pass form, for autodiff: group mean
+    of the H, W means, then the group mean of (x - mu)^2."""
+    n = p["scale"].shape[0]
+    bsz, _, _, nc = x.shape
+    g = min(groups, nc // n)
+
+    def group_mean(t):
+        t = t.reshape(bsz, n * g, -1)
+        t = jnp.broadcast_to(t.mean(axis=-1, keepdims=True), t.shape)
+        return t.reshape(bsz, nc)[:, None, None, :]
+
+    d = x - group_mean(x.mean(axis=(1, 2)))
+    var = group_mean(jnp.square(d).mean(axis=(1, 2)))
+    return (d * jax.lax.rsqrt(var + 1e-5) * p["scale"].reshape(-1)
+            + p["bias"].reshape(-1))
+
+
+def _gn_vjp(fn, p, x, dy, groups):
+    _, vjp = jax.vjp(lambda x, p: fn(p, x, groups), x, p)
+    dx, dp = vjp(dy)
+    return {"dx": dx, "d_scale": dp["scale"], "d_bias": dp["bias"]}
+
+
+GN_CASES = [(offset, n, c, groups) for offset in (0, 10, 1000)
+            for n, c, groups in ((1, 8, 4), (4, 8, 4), (4, 4, 8))]
+
+
+@pytest.mark.parametrize("offset,n,c,groups", GN_CASES, ids=[
+    f"offset{o}-C{n}-c{c}-g{g}" for o, n, c, g in GN_CASES])
+def test_groupnorm_vjp_is_as_precise_as_two_pass_autodiff(offset, n, c,
+                                                          groups):
+    """The packed GroupNorm's analytic VJP in float32 against float64
+    autodiff of the two-pass form, on inputs whose mean lies ``offset``
+    standard deviations from 0: each of dx, d_scale and d_bias is no
+    further from float64 than float32 autodiff of the same form, with
+    1.5x slack. A one-pass variance (E[x^2] - E[x]^2) or a backward that
+    reduces dy x - mu sum dy loses several digits at offset 1000."""
+    ks = jax.random.split(jax.random.PRNGKey(offset + 10 * n + c), 4)
+    shape = (B, IMAGE[0], IMAGE[1], n * c)
+    std = 2.0
+    x = std * (jax.random.normal(ks[0], shape) + offset)
+    dy = jax.random.normal(ks[1], shape)
+    p = {"scale": 1.0 + 0.5 * jax.random.normal(ks[2], (n, c)),
+         "bias": jax.random.normal(ks[3], (n, c))}
+    got = _gn_vjp(small._groupnorm_packed, p, x, dy, groups)
+    autodiff = _gn_vjp(_two_pass_groupnorm, p, x, dy, groups)
+    with jax.enable_x64(True):
+        up = lambda t: jnp.asarray(np.asarray(t, np.float64))
+        want = _gn_vjp(_two_pass_groupnorm, jax.tree.map(up, p), up(x),
+                       up(dy), groups)
+        want = jax.tree.map(np.asarray, want)
+    assert want["dx"].dtype == np.float64
+    for leaf in ("dx", "d_scale", "d_bias"):
+        assert got[leaf].dtype == jnp.float32
+        ref = np.linalg.norm(want[leaf])
+        ours = np.linalg.norm(np.asarray(got[leaf], np.float64)
+                              - want[leaf]) / ref
+        theirs = np.linalg.norm(np.asarray(autodiff[leaf], np.float64)
+                                - want[leaf]) / ref
+        assert ours <= 1.5 * theirs, (leaf, ours, theirs)
+
+
+def _walk(op):
+    for region in op.regions:
+        for block in region.blocks:
+            for inner in block.operations:
+                yield inner
+                yield from _walk(inner.operation)
+
+
+def _activation_sized(shape):
+    """An activation's shape: the batch and a spatial axis (B is no width,
+    and a per-image sum ``[B, C*c]`` has no spatial axis)."""
+    return B in shape and bool({IMAGE[0], IMAGE[0] // 2} & set(shape))
+
+
+def test_groupnorm_gradients_come_from_per_image_sums():
+    """On the lowered StableHLO of the packed ResNet's ``value_and_grad``:
+    every GroupNorm scale and bias gradient is a reduction of a ``[B,
+    C*c]`` array of per-image sums, not of an activation; each GroupNorm
+    reduces activations 5 times (H, W means of x and (x - mu)^2 forward;
+    sums of dy, dy (x - mu) and x - mu backward; autodiff made 6, two of
+    them over B, H and W), all under the ``groupnorm`` scope; the other
+    activation reductions are the conv biases' gradients and the pool."""
+    from jax._src.lib.mlir import ir
+
+    init, apply = _model("resnet", **MODELS["resnet"][1])
+    loss = make_loss(apply)
+    lead = (G, K)
+    params = jax.eval_shape(lambda: _stacked(init, lead))
+    batch = jax.eval_shape(lambda: _batch(lead))
+    module = jax.jit(jax.value_and_grad(
+        lambda p, b: jnp.sum(loss.packed(p, b)))).lower(
+            params, batch).compiler_ir("stablehlo")
+    main = next(op for op in module.body.operations
+                if ir.StringAttr(op.attributes["sym_name"]).value == "main")
+    outs = list(main.regions[0].blocks[0].operations[-1].operands)
+    leaves = jax.tree_util.tree_leaves_with_path(params)
+    assert len(outs) == 1 + len(leaves)        # the loss, then the gradient
+    gn_leaves = 0
+    for (path, _), value in zip(leaves, outs[1:]):
+        name = jax.tree_util.keystr(path)
+        if "gn" not in name:
+            continue
+        gn_leaves += 1
+        op = value.owner
+        while op.name in ("stablehlo.reshape", "stablehlo.convert"):
+            op = op.operands[0].owner
+        assert op.name == "stablehlo.reduce", name
+        operand = tuple(ir.RankedTensorType(op.operands[0].type).shape)
+        assert operand == (B, G * K * 8 * (2 if "s1" in name else 1)), (
+            name, operand)
+
+    reductions = [op for op in _walk(module.operation)
+                  if op.operation.name == "stablehlo.reduce"
+                  and _activation_sized(tuple(ir.RankedTensorType(
+                      op.operands[0].type).shape))]
+    scoped = [op for op in reductions
+              if re.search(r"(?<![\w.\-])groupnorm(?![\w.\-])",
+                           str(op.location))]
+    backward = [op for op in scoped if "transpose(" in str(op.location)]
+    norms = gn_leaves // 2
+    assert norms == 5        # stem, and gn1 and gn2 of both blocks
+    assert len(scoped) - len(backward) == 2 * norms
+    assert len(backward) == 3 * norms
+    convs = 6                # stem, c1 and c2 of both blocks, a projection
+    assert len(reductions) - len(scoped) == convs + 1
